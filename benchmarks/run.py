@@ -543,7 +543,7 @@ def bench_tuning(smoke: bool, retune: bool = False) -> dict:
     {tuning off, cached} x {fused, unfused epilogues} x {fp32, int8},
     min-of-5.
 
-    The Pallas kernels run in interpret mode on CPU hosts, so absolute
+    On a CPU host the Pallas kernels run in interpret mode, so those
     times are not TPU times — but the *ratios* exercise exactly what the
     autotuner controls: grid-step count and launch granularity per tile
     config, op count per fused epilogue, and arithmetic/traffic volume per
@@ -558,7 +558,7 @@ def bench_tuning(smoke: bool, retune: bool = False) -> dict:
 
     repeats = 5
     names = ["mlp"] if smoke else list(TUNING_WORKLOADS)
-    base = dict(backend="pallas", interpret=True)
+    base = dict(backend="pallas")
     out = {"bench": "tuning",
            "device_kind": E.tune.device_kind(),
            "cache_path": str(E.tune.cache_path()),
@@ -655,7 +655,7 @@ def _bench_int8_gate(repeats: int) -> dict:
     ys = {}
     for prec in ("fp32", "int8"):
         net = E.compile(prog_fused, E.EngineConfig(
-            backend="pallas", interpret=True, tuning="cached",
+            backend="pallas", tuning="cached",
             precision=prec))
         ys[prec] = jax.block_until_ready(net.apply(params, x))
         wall = float("inf")
@@ -709,107 +709,90 @@ def emit_serve_json(path: str, smoke: bool, emit=print) -> None:
     print(f"# wrote {path}", file=sys.stderr)
 
 
-_PARALLEL_WORKER = """
-import json, sys, time
-import jax, jax.numpy as jnp
-jax.config.update("jax_platform_name", "cpu")
-from repro import engine as E
-from repro.configs.base import reduced
-from repro.models import transformer as T
-from repro.serve import engine as SE
-from repro.serve.scheduler import Scheduler, latency_percentiles
+def _bench_parallel_mode(mode: dict) -> dict:
+    """One serve-parallel mode: the smoke prefill-scoring workload of
+    `bench_serve` through a `Scheduler` over the first data x model
+    devices, min-of-5 drains."""
+    import jax
+    import jax.numpy as jnp
 
-mode = json.loads(sys.argv[1])
-cfg = reduced("smollm_135m")
-params = T.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
-seq, n_req, max_batch, repeats = 32, 16, 8, 5
-prog = SE.prefill_program(cfg, batch=1, seq=seq, logits_only=True)
-
-mesh = None
-if mode["data"] * mode["model"] > 1:
+    from repro import engine as E
+    from repro.configs.base import reduced
     from repro.engine.parallel import ParallelConfig, make_mesh
-    pcfg = ParallelConfig(data=mode["data"], model=mode["model"],
-                          policy=mode["policy"])
-    scfg = E.EngineConfig(row_align=8, parallel=pcfg)
-    mesh = make_mesh(pcfg)
-else:
+    from repro.models import transformer as T
+    from repro.serve import engine as SE
+    from repro.serve.scheduler import Scheduler, latency_percentiles
+
+    cfg = reduced("smollm_135m")
+    params = T.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    seq, n_req, max_batch, repeats = 32, 16, 8, 5
+    prog = SE.prefill_program(cfg, batch=1, seq=seq, logits_only=True)
+    mesh = None
     scfg = E.EngineConfig(row_align=8)
+    if mode["data"] * mode["model"] > 1:
+        pcfg = ParallelConfig(data=mode["data"], model=mode["model"],
+                              policy=mode["policy"])
+        scfg = scfg.replace(parallel=pcfg)
+        mesh = make_mesh(pcfg)
 
-def requests():
-    return [{"tokens": jax.random.randint(jax.random.PRNGKey(i),
-                                          (1, seq), 0, cfg.vocab_size)}
-            for i in range(n_req)]
+    def requests():
+        return [{"tokens": jax.random.randint(jax.random.PRNGKey(i),
+                                              (1, seq), 0, cfg.vocab_size)}
+                for i in range(n_req)]
 
-sched = Scheduler(config=scfg, max_batch=max_batch, mesh=mesh)
-sched.register("score", prog, shared_args=(params,))
-sched.warmup("score")                   # every (bucket, replica) pre-paid
-wall, tickets = float("inf"), []
-for _ in range(repeats):
-    tickets = [sched.submit("score", r) for r in requests()]
-    t0 = time.perf_counter()
-    sched.drain()
-    wall = min(wall, time.perf_counter() - t0)
-print("RESULT", json.dumps({
-    "devices": jax.device_count(),
-    "replicas": sched.stats()["replicas"],
-    "wall_s": wall,
-    "throughput_rps": n_req / wall,
-    **latency_percentiles(tickets, (50, 95, 99)),
-}))
-"""
+    sched = Scheduler(config=scfg, max_batch=max_batch, mesh=mesh)
+    sched.register("score", prog, shared_args=(params,))
+    sched.warmup("score")               # every (bucket, replica) pre-paid
+    wall, tickets = float("inf"), []
+    for _ in range(repeats):
+        tickets = [sched.submit("score", r) for r in requests()]
+        t0 = time.perf_counter()
+        sched.drain()
+        wall = min(wall, time.perf_counter() - t0)
+    return {"devices": mode["data"] * mode["model"],
+            "replicas": sched.stats()["replicas"],
+            "wall_s": wall,
+            "throughput_rps": n_req / wall,
+            **latency_percentiles(tickets, (50, 95, 99))}
 
-# mode name -> (forced host devices, data, model, per-op policy)
-PARALLEL_MODES = {
-    "single":     {"devices": 1, "data": 1, "model": 1, "policy": "auto"},
-    "replicated": {"devices": 8, "data": 8, "model": 1, "policy": "auto"},
-    "sharded":    {"devices": 8, "data": 2, "model": 4, "policy": "auto"},
-}
+
+def parallel_modes(n_devices: int) -> dict:
+    """mode name -> (data, model, per-op policy) over the devices present:
+    one device, one replica per device, and 4-way model sharding with the
+    remaining devices as replicas (when 4 divides the device count)."""
+    modes = {"single": {"data": 1, "model": 1, "policy": "auto"}}
+    if n_devices > 1:
+        modes["replicated"] = {"data": n_devices, "model": 1,
+                               "policy": "auto"}
+    if n_devices % 4 == 0:
+        modes["sharded"] = {"data": n_devices // 4, "model": 4,
+                            "policy": "auto"}
+    return modes
 
 
 def bench_serve_parallel(smoke: bool) -> dict:
-    """Scheduler throughput on 1 vs 8 host devices, replica-spread vs
-    sharded vs single-device — the smoke prefill-scoring workload of
+    """Scheduler throughput on one device vs every device, replica-spread
+    vs sharded vs single-device — the smoke prefill-scoring workload of
     `bench_serve`, min-of-5 drains per mode.
 
-    Each mode runs in its own subprocess because jax pins the device count
-    at first init: `XLA_FLAGS=--xla_force_host_platform_device_count`
-    fakes the devices by splitting the host CPU, so all 8 "devices" share
-    one socket's FLOPs. The interesting ratios are therefore *overhead*
-    ratios (dispatch, collectives, shard_map) rather than real scaling —
-    the CI gate only asserts the parallel modes stay within a conservative
-    factor of single-device throughput, not that they beat it.
+    All modes run in this process, over subsets of `jax.devices()`: a chip
+    belongs to one process at a time. On a CPU host, get several devices
+    with `XLA_FLAGS=--xla_force_host_platform_device_count=8` set before
+    the run; they split one socket's FLOPs, so their ratios are overhead
+    ratios (dispatch, collectives, shard_map), not scaling.
     """
-    import os
-    import subprocess
-    from pathlib import Path
+    import jax
 
-    src = str(Path(__file__).resolve().parents[1] / "src")
-    modes = {}
-    for name, m in PARALLEL_MODES.items():
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count"
-                              f"={m['devices']}")
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        out = subprocess.run(
-            [sys.executable, "-c", _PARALLEL_WORKER, json.dumps(m)],
-            env=env, capture_output=True, text=True, timeout=1200)
-        if out.returncode != 0:
-            raise RuntimeError(f"parallel bench mode {name!r} failed:\n"
-                               + out.stderr[-4000:])
-        line = [l for l in out.stdout.splitlines()
-                if l.startswith("RESULT ")][-1]
-        modes[name] = {**m, **json.loads(line[len("RESULT "):])}
-
+    modes = {name: {**m, **_bench_parallel_mode(m)}
+             for name, m in parallel_modes(len(jax.devices())).items()}
     single = modes["single"]["throughput_rps"]
     return {
         "bench": "serve_parallel",
         "workload": {"program": "smollm-prefill32-logits", "requests": 16,
                      "max_batch": 8, "repeats": 5},
         "modes": modes,
-        "replicated_vs_single": modes["replicated"]["throughput_rps"]
-        / single,
-        "sharded_vs_single": modes["sharded"]["throughput_rps"] / single,
+        **{f"{name}_vs_single": modes[name]["throughput_rps"] / single
+           for name in ("replicated", "sharded") if name in modes},
     }
 
 
@@ -822,9 +805,9 @@ def emit_parallel_json(path: str, smoke: bool, emit=print) -> None:
         emit(f"serve_parallel/{name},{m['wall_s']/n_req*1e6:.0f},"
              f"rps={m['throughput_rps']:.1f};devices={m['devices']};"
              f"replicas={m['replicas']};p95_ms={m['p95_ms']:.2f}")
-    emit(f"serve_parallel/scaling,0,replicated_vs_single="
-         f"{result['replicated_vs_single']:.2f}x;sharded_vs_single="
-         f"{result['sharded_vs_single']:.2f}x")
+    ratios = ";".join(f"{k}={v:.2f}x" for k, v in result.items()
+                      if k.endswith("_vs_single"))
+    emit(f"serve_parallel/scaling,0,{ratios}")
     print(f"# wrote {path}", file=sys.stderr)
 
 
@@ -855,6 +838,8 @@ def main(argv=None) -> None:
                     help="machine-readable fault-degradation bench "
                          "output path")
     args = ap.parse_args(argv)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     if args.faults:
         print("name,us_per_call,derived")

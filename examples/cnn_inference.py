@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro import engine
 from repro.core.quant import ACT_FORMAT, WEIGHT_FORMAT, quantize
+from repro.launch import compile_cache
 from repro.models import cnn
 
 
@@ -36,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--policy", default="fixed", choices=["fixed", "auto"],
                     help="backend-selection policy for --compiled")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     net = args.net
     h, w, c = cnn.CNNS[net].input_hw_c

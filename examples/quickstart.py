@@ -12,12 +12,14 @@ import jax.numpy as jnp
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import reduced
 from repro.data import pipeline as dp
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.train import step as TS
 
 
 def main():
+    compile_cache.enable()
     cfg = reduced("smollm-135m")
     mesh = make_host_mesh()
     hyper = TS.TrainHyper(peak_lr=1e-3, warmup_steps=5, total_steps=30)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import reduced
+from repro.launch import compile_cache
 from repro.models import transformer as T
 from repro.serve.scheduler import ContinuousScheduler, latency_percentiles
 
@@ -66,7 +67,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    jax.config.update("jax_platform_name", "cpu")
+    compile_cache.enable()
     cfg = reduced("smollm_135m")
     params = T.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     work = build_workload(args.requests, args.seed)

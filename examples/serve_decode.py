@@ -7,13 +7,14 @@ tokens with the O(1)-state decode path (recurrent archs) or the KV cache
 """
 import argparse
 
-from repro.launch import serve as serve_mod
+from repro.launch import compile_cache, serve as serve_mod
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     serve_mod.main(["--arch", args.arch, "--reduced", "--batch", "4",
                     "--prompt-len", "48", "--gen", "24"])
 

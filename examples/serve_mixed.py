@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro import engine as E
 from repro.configs.base import reduced
+from repro.launch import compile_cache
 from repro.models import cnn, transformer as T
 from repro.serve import engine as SE
 from repro.serve.scheduler import Scheduler, latency_percentiles
@@ -32,7 +33,7 @@ def main(argv=None):
     ap.add_argument("--max-batch", type=int, default=4)
     args = ap.parse_args(argv)
 
-    jax.config.update("jax_platform_name", "cpu")
+    compile_cache.enable()
     cfg = reduced("smollm_135m")
     params = T.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
     cnn_params = cnn.init_cnn("alexnet", jax.random.PRNGKey(1))

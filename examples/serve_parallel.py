@@ -3,10 +3,11 @@
   PYTHONPATH=src python examples/serve_parallel.py [--data 2] [--model 4]
       [--requests 8] [--seed 0]
 
-Run it on a single-CPU box — the script re-execs itself with
-`XLA_FLAGS=--xla_force_host_platform_device_count=<data*model>` so jax
-fakes the devices (jax pins the device count at first init, which is why
-the flag must be set before any jax import in a fresh process).
+On a CPU host, JAX fakes the data*model devices: the script adds
+`--xla_force_host_platform_device_count=<data*model>` to XLA_FLAGS before
+JAX starts. The flag shapes only the CPU backend, so on a host with chips
+the mesh is built from the real devices (`--data 1 --model 4` or
+`--data 4 --model 1` on a four-chip host).
 
 Three layers of the parallel subsystem, smallest to largest:
 
@@ -29,7 +30,6 @@ shard-K, the one inexact strategy, is never auto-selected).
 """
 import argparse
 import os
-import sys
 
 
 def main():
@@ -42,14 +42,10 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    devices = args.data * args.model
-    if os.environ.get("_SERVE_PARALLEL_CHILD") != "1":
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count"
-                              f"={devices}")
-        env["_SERVE_PARALLEL_CHILD"] = "1"
-        os.execvpe(sys.executable, [sys.executable] + sys.argv, env)
+    # read when JAX first starts a backend, i.e. below; CPU backend only
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count"
+                                 f"={args.data * args.model}")
 
     import jax
     import jax.numpy as jnp
@@ -57,9 +53,11 @@ def main():
     from repro import engine as E
     from repro.configs.base import reduced
     from repro.engine.parallel import ParallelConfig, make_mesh
+    from repro.launch import compile_cache
     from repro.models import transformer as T
     from repro.serve.scheduler import ContinuousScheduler, ReplicaSpread
 
+    compile_cache.enable()
     print(f"devices: {jax.device_count()} "
           f"(mesh {args.data} data x {args.model} model)")
     cfg = reduced("smollm_135m")

@@ -8,7 +8,7 @@ path a cluster scheduler would exercise.
 import argparse
 import tempfile
 
-from repro.launch import train as train_mod
+from repro.launch import compile_cache, train as train_mod
 
 
 def main(argv=None):
@@ -18,6 +18,7 @@ def main(argv=None):
                     help="full config instead of the reduced variant")
     ap.add_argument("--steps", type=int, default=60)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     with tempfile.TemporaryDirectory() as ckpt:
         base = ["--arch", args.arch, "--seq", "128", "--batch", "8",

@@ -5,9 +5,10 @@ The PR-4/PR-8 autotuner contracts, all decidable without benchmarking:
   * a pinned dense tile must stay MXU-aligned for its precision — the M
     block on the precision's sublane (8 rows fp32, 32 rows int8), the K/N
     blocks on the 128 lane — or the kernel pays pad/repack on every step;
-  * a pinned tile's VMEM footprint (operand tiles + fp32/int32 accumulator)
-    must fit `modes.VMEM_BYTES`, the same guard the candidate generator
-    applies — a hand-edited or stale cache entry can violate it;
+  * a pinned tile's VMEM footprint (`tune.dense_vmem_bytes`: double-
+    buffered blocks plus accumulators) must fit `tune.SCOPED_VMEM_BYTES`,
+    the limit the kernels compile under and the candidate generator's
+    guard — a hand-edited or stale cache entry can violate it;
   * a cache entry's recorded precision must agree with the precision the
     key was derived for (fp32 winners must not leak onto the int8 path).
 
@@ -24,7 +25,6 @@ import json
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
-from repro.core import modes
 from repro.engine import tune as tunelib
 from repro.engine.config import EngineConfig
 from repro.engine.plan import EnginePlan, OpSpec
@@ -38,8 +38,9 @@ register_rule(Rule(
              "multiples of the 128 lane"))
 register_rule(Rule(
     id="tile-vmem-overflow", severity="error", layer="tile",
-    contract="a pinned tile's VMEM footprint (operand tiles + accumulator) "
-             "must fit modes.VMEM_BYTES, the candidate generator's guard"))
+    contract="a pinned tile's VMEM footprint (double-buffered blocks + "
+             "accumulators) must fit tune.SCOPED_VMEM_BYTES, the scoped "
+             "limit the kernels compile under"))
 register_rule(Rule(
     id="tile-precision-mismatch", severity="error", layer="tile",
     contract="a cache entry's recorded precision must match the precision "
@@ -61,15 +62,6 @@ def sublane_rows(precision: str) -> int:
     return 32 if precision == "int8" else 8
 
 
-def dense_tile_vmem(tile: Sequence[int], precision: str) -> int:
-    """VMEM bytes of a dense (bm, bk, bn) tile — the exact formula of
-    `tune._dense_candidates` (1-byte operands + int32 accumulator for
-    int8; fp32 operands + fp32 accumulator + bias row otherwise)."""
-    bm, bk, bn = (int(v) for v in tile)
-    elt = 1 if precision == "int8" else 4
-    return elt * (bm * bk + bk * bn) + 4 * (bm * bn + bn)
-
-
 def check_dense_tile(tile: Sequence[int], precision: str,
                      site: str) -> List[Diagnostic]:
     """Alignment + VMEM findings for one pinned dense tile."""
@@ -89,12 +81,12 @@ def check_dense_tile(tile: Sequence[int], precision: str,
             "tile-misaligned", site, "; ".join(bad),
             fix="re-tune the op (python -m benchmarks.run --retune) or "
                 "drop the entry so the kernel default applies"))
-    vmem = dense_tile_vmem((bm, bk, bn), precision)
-    if vmem > modes.VMEM_BYTES:
+    vmem = tunelib.dense_vmem_bytes((bm, bk, bn), precision)
+    if vmem > tunelib.SCOPED_VMEM_BYTES:
         out.append(finding(
             "tile-vmem-overflow", site,
             f"tile ({bm}, {bk}, {bn}) needs {vmem} VMEM bytes > "
-            f"{modes.VMEM_BYTES} budget",
+            f"{tunelib.SCOPED_VMEM_BYTES} scoped limit",
             fix="re-tune the op; the candidate generator never emits "
                 "over-budget tiles"))
     return out

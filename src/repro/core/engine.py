@@ -37,7 +37,7 @@ Backend = Literal["pallas", "xla", "ref"]
 @dataclasses.dataclass
 class EngineConfig:
     backend: Backend = "xla"
-    interpret: bool = True          # Pallas interpret mode (CPU container)
+    interpret: Optional[bool] = None  # None: interpret exactly on a CPU
     accum_dtype: jnp.dtype = jnp.float32
     track_analytics: bool = True
 

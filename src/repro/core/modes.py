@@ -32,12 +32,39 @@ MMIE_SCRATCH_ENTRIES = 64    # L = 64 24-bit partial sums per PE
 # layer only pays when the compute saved outweighs the words moved.
 MMIE_LINK_WORDS_PER_CYCLE = 1
 
-# TPU v5e target constants (roofline; see EXPERIMENTS.md §Roofline).
-TPU_PEAK_FLOPS_BF16 = 197e12     # per chip
-TPU_HBM_BW = 819e9               # bytes/s per chip
-TPU_ICI_BW = 50e9                # bytes/s per link
 MXU_TILE = (128, 128)            # systolic array
-VMEM_BYTES = 128 * 1024 * 1024   # v5e VMEM per core (approx usable)
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float            # MXU FLOP/s, bf16 operands
+    hbm_bw: float                # HBM bytes/s
+    ici_bw: float                # chip-to-chip bytes/s per link
+    source: str
+
+
+# Keyed by `jax.Device.device_kind`. A kind that is not here has no peaks:
+# `peaks_for` raises rather than price it as some other chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "HBM at 819 GB/s, 1,600 Gbit/s ICI per chip over 4 links"),
+}
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """The published peaks of `device_kind` (`jax.devices()[0].device_kind`).
+    Raises KeyError for a kind the table does not hold."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)} (add it to core.modes.DEVICE_PEAKS "
+            "with its source)") from None
 
 
 def pes_per_tile(w_f: int, s: int) -> int:

@@ -170,7 +170,7 @@ def _plan_for(op: planlib.OpSpec,
     return planlib.plan_op(op, name)
 
 
-def _interp(interpret: Optional[bool]) -> bool:
+def _interp(interpret: Optional[bool]) -> Optional[bool]:
     return current_config().interpret if interpret is None else interpret
 
 

@@ -43,7 +43,10 @@ class EngineConfig:
     backend   — registry name ("xla" | "pallas" | "ref" | custom); with
                 policy="auto" it is the fallback for layers the auto policy
                 does not send to Pallas.
-    interpret — run Pallas kernels in interpret mode (True on CPU hosts).
+    interpret — run Pallas kernels in interpret mode. None (default)
+                derives it from the platform: True exactly where JAX's
+                default backend is the CPU (`kernels.ops.resolve_interpret`),
+                so a TPU never interprets a kernel unless asked to.
     accum     — accumulation policy: None keeps each op's own default
                 (fp32 for conv2d/dense, native for einsum); "native" forces
                 plain `@` numerics; any dtype name ("float32", "bfloat16")
@@ -107,7 +110,7 @@ class EngineConfig:
     """
 
     backend: str = "xla"
-    interpret: bool = True
+    interpret: Optional[bool] = None
     accum: Optional[str] = None
     policy: str = "fixed"
     row_align: Optional[int] = None

@@ -6,8 +6,8 @@ the engine against a precomputed `EnginePlan`:
 
   * ``"xla"``    — pure-JAX GFID lowering (`core.gfid` shifted GEMMs); the
                    default everywhere.
-  * ``"pallas"`` — `repro.kernels` Pallas TPU kernels (interpret=True on the
-                   CPU container, Mosaic on TPU).
+  * ``"pallas"`` — `repro.kernels` Pallas TPU kernels (Mosaic on TPU;
+                   interpreted only where JAX's default backend is the CPU).
   * ``"ref"``    — XLA's native conv / dot: the "direct engine" baseline the
                    paper compares the dataflow against.
 

@@ -92,18 +92,26 @@ class ParallelConfig:
 # mesh plumbing
 # ---------------------------------------------------------------------------
 
+def device_mesh(shape, axes):
+    """A `Mesh` of `shape` named `axes` over the first prod(shape) local
+    devices. Its axes are Auto: the sharding rules place activations with
+    `with_sharding_constraint`, which may only name Auto axes (on jax 0.9
+    `jax.make_mesh` makes Explicit ones unless told otherwise)."""
+    import numpy as np
+    shape, devs = tuple(int(v) for v in shape), jax.devices()
+    if len(devs) < math.prod(shape):
+        raise ValueError(
+            f"a {dict(zip(axes, shape))} mesh needs {math.prod(shape)} "
+            f"devices but only {len(devs)} exist (force host devices with "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=N before "
+            "first jax use, or shrink the mesh)")
+    arr = np.asarray(devs[:math.prod(shape)]).reshape(shape)
+    return jax.sharding.Mesh(arr, tuple(axes))
+
+
 def make_mesh(pcfg: ParallelConfig):
     """A (data, model) `Mesh` over the first `pcfg.devices` local devices."""
-    import numpy as np
-    devs = jax.devices()
-    if len(devs) < pcfg.devices:
-        raise ValueError(
-            f"ParallelConfig wants data={pcfg.data} x model={pcfg.model} = "
-            f"{pcfg.devices} devices but only {len(devs)} exist (force host "
-            "devices with XLA_FLAGS=--xla_force_host_platform_device_count=N "
-            "before first jax use, or shrink the config)")
-    arr = np.asarray(devs[:pcfg.devices]).reshape(pcfg.data, pcfg.model)
-    return jax.sharding.Mesh(arr, ("data", "model"))
+    return device_mesh((pcfg.data, pcfg.model), ("data", "model"))
 
 
 def check_mesh(mesh, pcfg: ParallelConfig) -> None:

@@ -42,6 +42,7 @@ from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.core import modes
+from repro.core.quant import INT8_EXACT_K
 from repro.engine import plan as planlib
 from repro.engine.config import EngineConfig
 
@@ -62,16 +63,32 @@ BENCH_REPEATS = 3           # min-of-N wallclock per candidate
 # (mxu_occupancy) captures for the MMIE array.
 LAUNCH_MACS = 1 << 20
 
-def _default_dir() -> Path:
-    """`.tuning/` anchored at the repo root when one is detectable (walk up
-    from this file for a pyproject.toml / .git marker), else CWD-relative.
-    Anchoring means `tuning="cached"` finds the committed cache — and
-    `--retune` refreshes it — no matter which directory the process was
-    launched from; the CWD fallback covers installed-package layouts."""
+# The scoped-VMEM limit the Pallas kernels compile under. They pass no
+# `vmem_limit_bytes`, so Mosaic's default for TPU v5e holds: 16 MiB. A
+# (512, 1792, 512) fp32 GEMM tile, 17 MiB with its buffers, is refused with
+# RESOURCE_EXHAUSTED; (512, 1536, 512), 15 MiB, compiles. Pallas double-
+# buffers every pipelined block, so footprints below count inputs and
+# outputs twice. tests/test_tpu_compile.py compiles the largest kept tile.
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def repo_root() -> Optional[Path]:
+    """The checkout this module lives in (the nearest parent holding a
+    pyproject.toml or .git), or None for an installed package."""
     for parent in Path(__file__).resolve().parents:
         if (parent / "pyproject.toml").exists() or (parent / ".git").exists():
-            return parent / ".tuning"
-    return Path(".tuning")
+            return parent
+    return None
+
+
+def _default_dir() -> Path:
+    """`.tuning/` anchored at the repo root when one is detectable, else
+    CWD-relative. Anchoring means `tuning="cached"` finds the committed
+    cache — and `--retune` refreshes it — no matter which directory the
+    process was launched from; the CWD fallback covers installed-package
+    layouts."""
+    root = repo_root()
+    return root / ".tuning" if root is not None else Path(".tuning")
 
 
 _dir_override: Optional[Path] = None  # analyze: allow[mutable-global] test-only cache-dir override
@@ -224,6 +241,43 @@ def _accum_label(cfg: EngineConfig) -> Optional[str]:
 _round_up = modes.round_up
 
 
+def _buf(rows: int, cols: int, elt: int) -> int:
+    """VMEM bytes of a (rows, cols) buffer laid out in (sublane, 128) tiles:
+    the sublane packs 32 bytes per lane column (8 rows fp32, 32 int8)."""
+    return (_round_up(max(rows, 1), 32 // elt) * _round_up(max(cols, 1), 128)
+            * elt)
+
+
+def dense_vmem_bytes(tile: Sequence[int], precision: str = "fp32") -> int:
+    """VMEM footprint of one (bm, bk, bn) GEMM grid step: double-buffered
+    operand, bias and output blocks plus the dot's (bm, bn) fp32 result.
+    int8 adds the int32 accumulator scratch, the lane-padded (bm, 1) row
+    scales, and the fp32 casts of one INT8_EXACT_K contraction chunk."""
+    bm, bk, bn = (int(v) for v in tile)
+    out = 2 * _buf(bm, bn, 4) + _buf(bm, bn, 4)
+    if precision != "int8":
+        return 2 * (_buf(bm, bk, 4) + _buf(bk, bn, 4) + _buf(1, bn, 4)) + out
+    kc = min(bk, INT8_EXACT_K)
+    return (2 * (_buf(bm, bk, 1) + _buf(bk, bn, 1) + _buf(bm, 1, 4)
+                 + 2 * _buf(1, bn, 4))
+            + out + _buf(bm, bn, 4) + _buf(bm, kc, 4) + _buf(kc, bn, 4))
+
+
+def conv_vmem_bytes(op: planlib.OpSpec, tile: Sequence[int]) -> int:
+    """VMEM footprint of one GFID conv grid step at channel tile (cib, cob):
+    the double-buffered phase-split input row, W_f filter taps, bias row
+    and output row, the fp32 accumulator, and one more output-row buffer
+    for the int8 kernel's int32 scratch. Counted at 4 bytes per element,
+    which bounds the int8 kernel's blocks and their fp32 casts too."""
+    cib, cob = (int(v) for v in tile)
+    w_in, w_f, s = op.x_shape[2] + 2 * op.pad, op.w_shape[1], op.stride
+    w_out = (w_in - w_f) // s + 1
+    x_row = s * _buf(-(-w_in // s), cib, 4)
+    taps = w_f * _buf(cib, cob, 4)
+    out_row = _buf(w_out, cob, 4)
+    return 2 * (x_row + taps + _buf(1, cob, 4) + out_row) + 2 * out_row
+
+
 @dataclasses.dataclass(frozen=True)
 class Candidate:
     tile: Tile
@@ -233,23 +287,21 @@ class Candidate:
 def _dense_candidates(m: int, k: int, n: int,
                       precision: str = "fp32") -> List[Candidate]:
     """MXU-aligned (bm, bk, bn) grid for an (M, K) @ (K, N) GEMM, scored by
-    padded MACs + launch overhead, VMEM-guarded. int8 candidates align the
-    M block to the 32-row int8 sublane (fp32 packs 8 rows per sublane,
-    int8 packs 32) and budget 1-byte operand tiles plus the int32 VMEM
-    accumulator."""
+    padded MACs + launch overhead, guarded by `SCOPED_VMEM_BYTES`. int8
+    candidates align the M block to the 32-row int8 sublane (fp32 packs 8
+    rows per sublane, int8 packs 32)."""
     sub = 32 if precision == "int8" else 8
     mp8, kp, np_ = _round_up(m, sub), _round_up(k, 128), _round_up(n, 128)
     bms = sorted({v for v in (sub, 64, 128, 256, mp8)
                   if v <= mp8 and v % sub == 0})
     bks = sorted({v for v in (128, 256, 512, 1024, kp) if v <= kp})
     bns = sorted({v for v in (128, 256, 512, 1024, np_) if v <= np_})
-    elt = 1 if precision == "int8" else 4
     out: List[Candidate] = []
     for bm in bms:
         for bk in bks:
             for bn in bns:
-                vmem = elt * (bm * bk + bk * bn) + 4 * (bm * bn + bn)
-                if vmem > modes.VMEM_BYTES:
+                if dense_vmem_bytes((bm, bk, bn),
+                                    precision) > SCOPED_VMEM_BYTES:
                     continue
                 mp = _round_up(m, bm)
                 kpp = _round_up(k, bk)
@@ -279,9 +331,7 @@ def _conv_candidates(op: planlib.OpSpec) -> List[Candidate]:
     out: List[Candidate] = []
     for cib in _divisor_tiles(cg):
         for cob in _divisor_tiles(og):
-            vmem = 4 * ((w_in + 2 * op.pad) * cib + w_f * cib * cob
-                        + w_out * cob)
-            if vmem > modes.VMEM_BYTES:
+            if conv_vmem_bytes(op, (cib, cob)) > SCOPED_VMEM_BYTES:
                 continue
             steps = (op.groups * b * h_out * (og // cob) * h_f * (cg // cib))
             # x rows are re-read once per C_out tile; w once per step

@@ -12,6 +12,12 @@ same output block consecutively, accumulating in place, exactly like the
 paper's PEs accumulate C_in x H_f partial products per output pixel
 (§4: "this procedure is repeated H_f x C_in times").
 
+Stride S > 1: the wrapper splits each input row into its S stride phases,
+(B, H, W, C) -> (B, H, S, ceil(W/S), C), so filter tap i reads phase i % S
+at offset i // S as one contiguous slice. Mosaic refuses a strided slice
+of a loaded vector; the phase split keeps the tap order, and so the
+accumulation order, of the plain strided loop.
+
 Channel tiling `(c_in_block, c_out_block)` is an explicit knob (the
 per-layer resource adaptation of `engine.tune`): ragged channel counts fall
 back to whole-channel blocks. Optional fused epilogue: `bias` (C_out,)
@@ -35,14 +41,35 @@ from repro.kernels.epilogue import ACTS, dequant_epilogue
 DEFAULT_CONV_TILE = (512, 256)      # (c_in_block, c_out_block)
 
 
+def _phase_split(x: jax.Array, stride: int) -> jax.Array:
+    """(B, H, W, C) -> (B, H, S, ceil(W/S), C): phase p holds the columns
+    p, p + S, p + 2S, ... of every row. The zero columns padded on to make
+    W divisible by S are never read by a tap of a valid output column."""
+    b, h, w, c = x.shape
+    if stride == 1:
+        return x.reshape(b, h, 1, w, c)
+    wp = -(-w // stride)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, wp * stride - w), (0, 0)))
+    return x.reshape(b, h, wp, stride, c).transpose(0, 1, 3, 2, 4)
+
+
+def _tap(x_ref, i: int, stride: int, w_out: int) -> jax.Array:
+    """The (W_out, C_in_blk) input slice filter tap `i` multiplies: output
+    column o reads input column o * S + i, i.e. phase i % S, row o + i // S."""
+    q, p = divmod(i, stride)
+    return x_ref[0, 0, p, q:q + w_out, :]
+
+
+def _x_spec(xp: jax.Array, stride: int, cib: int) -> pl.BlockSpec:
+    """One phase-split input row, all S phases, one C_in block."""
+    return pl.BlockSpec((1, 1, stride, xp.shape[3], cib),
+                        lambda bi, z, co, j, k: (bi, z * stride + j, 0, 0, k))
+
+
 def _accumulate(x_ref, w_ref, o_ref, *, w_f: int, stride: int, w_out: int):
-    xv = x_ref[0, 0]                          # (W_in_pad, C_in_blk) VMEM
     acc = jnp.zeros((w_out, o_ref.shape[-1]), jnp.float32)
     for i in range(w_f):                      # the W_f weight-register loop
-        xs = jax.lax.slice(xv, (i, 0),
-                           (i + (w_out - 1) * stride + 1, xv.shape[1]),
-                           (stride, 1))
-        acc += jnp.dot(xs, w_ref[0, i],
+        acc += jnp.dot(_tap(x_ref, i, stride, w_out), w_ref[0, i],
                        preferred_element_type=jnp.float32)
     o_ref[0, 0] += acc
 
@@ -104,8 +131,8 @@ def gfid_conv2d_nhwc(x: jax.Array, w: jax.Array, *, stride: int = 1,
     n_ci, n_co = c_in // cib, c_out // cob
 
     grid = (b, h_out, n_co, h_f, n_ci)
-    x_spec = pl.BlockSpec((1, 1, w_in, cib),
-                          lambda bi, z, co, j, k: (bi, z * stride + j, 0, k))
+    xp = _phase_split(x, stride)
+    x_spec = _x_spec(xp, stride, cib)
     w_spec = pl.BlockSpec((1, w_f, cib, cob),
                           lambda bi, z, co, j, k: (j, 0, k, co))
     o_spec = pl.BlockSpec((1, 1, w_out, cob),
@@ -115,7 +142,7 @@ def gfid_conv2d_nhwc(x: jax.Array, w: jax.Array, *, stride: int = 1,
         return pl.pallas_call(
             functools.partial(_kernel, w_f=w_f, stride=stride, w_out=w_out),
             grid=grid, in_specs=[x_spec, w_spec], out_specs=o_spec,
-            out_shape=out_shape, interpret=interpret)(x, w)
+            out_shape=out_shape, interpret=interpret)(xp, w)
     bv = (jnp.zeros((c_out,), jnp.float32) if bias is None
           else bias.astype(jnp.float32)).reshape(1, c_out)
     b_spec = pl.BlockSpec((1, cob), lambda bi, z, co, j, k: (0, co))
@@ -124,7 +151,7 @@ def gfid_conv2d_nhwc(x: jax.Array, w: jax.Array, *, stride: int = 1,
                           w_out=w_out, last_j=h_f - 1, last_k=n_ci - 1,
                           act=act),
         grid=grid, in_specs=[x_spec, w_spec, b_spec], out_specs=o_spec,
-        out_shape=out_shape, interpret=interpret)(x, w, bv)
+        out_shape=out_shape, interpret=interpret)(xp, w, bv)
 
 
 def _accumulate_int8(x_ref, w_ref, acc_ref, *, w_f: int, stride: int,
@@ -136,13 +163,10 @@ def _accumulate_int8(x_ref, w_ref, acc_ref, *, w_f: int, stride: int,
     exactly-represented integer (< 2²⁴) — the in-kernel twin of
     `core.quant.int8_matmul_i32`. Order-independent integer math keeps the
     Pallas result bitwise identical to the xla/ref quantized paths."""
-    xv = x_ref[0, 0]                          # (W_in_pad, C_in_blk) int8
-    cib = xv.shape[1]
+    cib = x_ref.shape[-1]
     acc = jnp.zeros(acc_ref.shape, jnp.int32)
     for i in range(w_f):
-        xs = jax.lax.slice(xv, (i, 0),
-                           (i + (w_out - 1) * stride + 1, cib),
-                           (stride, 1))
+        xs = _tap(x_ref, i, stride, w_out)    # (W_out, C_in_blk) int8
         wv = w_ref[0, i]                      # (C_in_blk, cob) int8
         for c0 in range(0, max(cib, 1), INT8_EXACT_K):
             acc += jnp.dot(
@@ -167,7 +191,7 @@ def _kernel_int8(x_ref, w_ref, sx_ref, sw_ref, b_ref, o_ref, acc_ref, *,
 
     @pl.when((j == last_j) & (k == last_k))
     def _epilogue():
-        scale = sx_ref[...] * sw_ref[...]     # (1, 1) * (1, cob)
+        scale = sx_ref[0] * sw_ref[...]       # (1, 1) * (1, cob)
         o_ref[0, 0] = dequant_epilogue(
             acc_ref[...], scale, b_ref[...] if has_bias else None, act)
 
@@ -203,11 +227,13 @@ def gfid_conv2d_nhwc_int8(xq: jax.Array, wq: jax.Array, sx: jax.Array,
     n_ci, n_co = c_in // cib, c_out // cob
 
     grid = (b, h_out, n_co, h_f, n_ci)
-    x_spec = pl.BlockSpec((1, 1, w_in, cib),
-                          lambda bi, z, co, j, k: (bi, z * stride + j, 0, k))
+    xp = _phase_split(xq, stride)
+    x_spec = _x_spec(xp, stride, cib)
     w_spec = pl.BlockSpec((1, w_f, cib, cob),
                           lambda bi, z, co, j, k: (j, 0, k, co))
-    sx_spec = pl.BlockSpec((1, 1), lambda bi, z, co, j, k: (bi, 0))
+    # (B, 1, 1): a (1, 1) block of a (B, 1) array breaks the TPU's (8, 128)
+    # block rule for any B > 1; here the block's last two dims are whole
+    sx_spec = pl.BlockSpec((1, 1, 1), lambda bi, z, co, j, k: (bi, 0, 0))
     sw_spec = pl.BlockSpec((1, cob), lambda bi, z, co, j, k: (0, co))
     b_spec = pl.BlockSpec((1, cob), lambda bi, z, co, j, k: (0, co))
     o_spec = pl.BlockSpec((1, 1, w_out, cob),
@@ -225,5 +251,5 @@ def gfid_conv2d_nhwc_int8(xq: jax.Array, wq: jax.Array, sx: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, h_out, w_out, c_out),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((w_out, cob), jnp.int32)],
-        interpret=interpret)(xq, wq, sx.astype(jnp.float32),
+        interpret=interpret)(xp, wq, sx.astype(jnp.float32).reshape(b, 1, 1),
                              sw.astype(jnp.float32), bv)

@@ -1,10 +1,11 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On this CPU container every kernel runs with interpret=True (the kernel
-body executes in Python, validating the BlockSpec tiling and accumulation
-logic); on TPU the same calls compile to Mosaic. The wrappers add padding,
-grouping, batching and dtype plumbing so callers see the same contract as
-the pure-jnp references in ref.py / core.gfid.
+On a TPU every kernel compiles to Mosaic. Where JAX's default backend is
+the CPU they run in interpret mode (the kernel body executes in Python,
+checking the BlockSpec tiling and accumulation logic); `interpret=None`,
+the default, picks between the two (`resolve_interpret`). The wrappers add
+padding, grouping, batching and dtype plumbing so callers see the same
+contract as the pure-jnp references in ref.py / core.gfid.
 """
 from __future__ import annotations
 
@@ -22,10 +23,17 @@ from repro.kernels import gfid_matmul as _matmul
 from repro.kernels import paged as _paged
 
 
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """`interpret` when given, else True exactly where JAX's default backend
+    is the CPU: Pallas kernels can only be interpreted there, and must never
+    be on a TPU."""
+    return jax.default_backend() == "cpu" if interpret is None else interpret
+
+
 def gfid_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int = 0,
                 groups: int = 1, tile: Optional[Tuple[int, int]] = None,
                 bias: Optional[jax.Array] = None, act: Optional[str] = None,
-                interpret: bool = True,
+                interpret: Optional[bool] = None,
                 precision: str = "fp32") -> jax.Array:
     """NHWC x HWIO conv through the multi-mode engine's conv mode.
 
@@ -44,6 +52,7 @@ def gfid_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int = 0,
     the old eager Python loop that emitted `groups` separate kernel launches
     plus a concatenate.
     """
+    interpret = resolve_interpret(interpret)
     if precision == "int8":
         return _gfid_conv2d_int8(x, w, stride=stride, pad=pad, groups=groups,
                                  tile=tile, bias=bias, act=act,
@@ -124,7 +133,7 @@ def _gfid_conv2d_int8(x: jax.Array, w: jax.Array, *, stride: int, pad: int,
 def gfid_matmul(x: jax.Array, w: jax.Array, *,
                 tile: Optional[Tuple[int, int, int]] = None,
                 bias: Optional[jax.Array] = None, act: Optional[str] = None,
-                interpret: bool = True,
+                interpret: Optional[bool] = None,
                 precision: str = "fp32") -> jax.Array:
     """(..., K) @ (K, N) through the FC mode.
 
@@ -132,6 +141,7 @@ def gfid_matmul(x: jax.Array, w: jax.Array, *,
     default); `bias` (N,) and `act` run as a fused in-kernel epilogue.
     `precision="int8"` quantizes per-row (x) / per-column (w) and runs the
     exact-int32-accumulator kernel with the fused dequant epilogue."""
+    interpret = resolve_interpret(interpret)
     bm, bk, bn = tile if tile is not None else _matmul.DEFAULT_TILE
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
@@ -147,22 +157,24 @@ def gfid_matmul(x: jax.Array, w: jax.Array, *,
 
 
 def gfid_conv1d_depthwise(x: jax.Array, w: jax.Array, *, causal: bool = True,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: Optional[bool] = None) -> jax.Array:
     return _conv1d.gfid_conv1d_depthwise(
-        x, w, causal=causal, interpret=interpret).astype(x.dtype)
+        x, w, causal=causal,
+        interpret=resolve_interpret(interpret)).astype(x.dtype)
 
 
 def paged_gather(pool: jax.Array, table: jax.Array, *,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """Paged-KV block gather: pool (num_blocks, block_size, *feature) indexed
     by table (B, blocks_per_req) int32 -> (B, blocks_per_req * block_size,
     *feature). Bitwise identical to the XLA `jnp.take` reference — a gather
     is a copy, so there is no accumulation-order caveat."""
-    return _paged.paged_gather(pool, table, interpret=interpret)
+    return _paged.paged_gather(pool, table,
+                               interpret=resolve_interpret(interpret))
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (B, Sq, H, D); k, v: (B, Skv, KV, D) — GQA broadcast inside.
     Returns (B, Sq, H, D)."""
     b, sq, h, d = q.shape
@@ -172,5 +184,5 @@ def flash_attention(q, k, v, *, causal: bool = True, scale=None,
     kt = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1)
     vt = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1)
     out = _flash.flash_attention(qt, kt, vt, causal=causal, scale=scale,
-                                 interpret=interpret)
+                                 interpret=resolve_interpret(interpret))
     return out.transpose(0, 2, 1, 3)

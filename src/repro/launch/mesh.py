@@ -6,17 +6,20 @@ outermost data-parallel axis (DCN between pods; all sharding rules treat
 batch as (pod, data)).
 
 Functions, not module constants — importing this module never touches jax
-device state (the dry-run sets XLA_FLAGS before first jax init).
+device state (the dry-run sets XLA_FLAGS before first jax init). Every mesh
+comes from `engine.parallel.device_mesh`, the one builder.
 """
 from __future__ import annotations
 
 import jax
 
+from repro.engine.parallel import device_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return device_mesh(shape, axes)
 
 
 def snap_model(n_devices: int, model: int) -> int:
@@ -40,4 +43,4 @@ def make_host_mesh(model: int = 1):
     """
     n = len(jax.devices())
     model = snap_model(n, model)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return device_mesh((n // model, model), ("data", "model"))

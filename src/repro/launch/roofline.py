@@ -2,18 +2,21 @@
 
 Reads the dry-run artifacts (experiments/dryrun/*.json) and merges them with
 the analytic FLOP/byte model (core/flops.py) into the three-term roofline
-per (arch x shape) cell on the single-pod mesh:
+per (arch x shape) cell on the single-pod mesh, against the published
+peaks of the named device kind (`core.modes.DEVICE_PEAKS`; a kind missing
+from the table is an error):
 
-  compute term    = FLOPs / (chips x 197 TFLOP/s bf16)
-  memory term     = HBM bytes / (chips x 819 GB/s)
-  collective term = collective bytes per chip / 50 GB/s ICI
+  compute term    = FLOPs / (chips x peak bf16 FLOP/s)
+  memory term     = HBM bytes / (chips x peak HBM bytes/s)
+  collective term = collective bytes per chip / ICI bytes/s per link
 
 FLOPs source: analytic (exact matmul census from the config — XLA's
 HloCostAnalysis counts while bodies once, see launch/hloparse.py docstring);
 the compiled number and the MODEL_FLOPS = 6*N_active*D ratio are reported
 alongside. Collective bytes: trip-count-corrected HLO parse (per-device).
 
-  PYTHONPATH=src python -m repro.launch.roofline [--mesh 16x16] [--md]
+  PYTHONPATH=src python -m repro.launch.roofline --device-kind "TPU v5 lite" \
+      [--mesh 16x16] [--md]
 """
 from __future__ import annotations
 
@@ -23,12 +26,14 @@ from pathlib import Path
 
 from repro.configs.base import ARCH_NAMES, SHAPES, get_config, valid_cells
 from repro.core import flops as F
-from repro.core.modes import TPU_HBM_BW, TPU_ICI_BW, TPU_PEAK_FLOPS_BF16
+from repro.core.modes import peaks_for
 
 DRYRUN_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
 
-def cell_roofline(arch: str, cell: str, mesh_tag: str = "16x16"):
+def cell_roofline(arch: str, cell: str, mesh_tag: str = "16x16", *,
+                  device_kind: str):
+    peaks = peaks_for(device_kind)
     cfg = get_config(arch)
     n_dev = 512 if mesh_tag == "2x16x16" else 256
     path = DRYRUN_DIR / f"{arch}__{cell}__{mesh_tag}.json"
@@ -36,11 +41,11 @@ def cell_roofline(arch: str, cell: str, mesh_tag: str = "16x16"):
 
     cf = F.cell_flops(cfg, cell)
     per_dev_flops = cf.cell_total / n_dev
-    compute_term = per_dev_flops / TPU_PEAK_FLOPS_BF16
+    compute_term = per_dev_flops / peaks.flops_bf16
 
     bytes_dev = F.cell_bytes_per_device(cfg, cell, n_dev)
     hbm_bytes = sum(bytes_dev.values())
-    memory_term = hbm_bytes / TPU_HBM_BW
+    memory_term = hbm_bytes / peaks.hbm_bw
 
     coll_bytes = 0
     coll_detail = {}
@@ -51,7 +56,7 @@ def cell_roofline(arch: str, cell: str, mesh_tag: str = "16x16"):
         hlo_flops = dj["cost"]["flops"]
         hlo_bytes = dj["cost"]["bytes_accessed"]
         peak_gib = (dj["memory"]["peak_bytes"] or 0) / 2 ** 30
-    collective_term = coll_bytes / TPU_ICI_BW
+    collective_term = coll_bytes / peaks.ici_bw
 
     terms = {"compute": compute_term, "memory": memory_term,
              "collective": collective_term}
@@ -60,6 +65,7 @@ def cell_roofline(arch: str, cell: str, mesh_tag: str = "16x16"):
     roofline_fraction = compute_term / bound if bound else 0.0
     return {
         "arch": arch, "cell": cell, "mesh": mesh_tag,
+        "device_kind": device_kind,
         "compute_s": compute_term, "memory_s": memory_term,
         "collective_s": collective_term, "dominant": dominant,
         "roofline_fraction": roofline_fraction,
@@ -74,12 +80,13 @@ def cell_roofline(arch: str, cell: str, mesh_tag: str = "16x16"):
     }
 
 
-def all_rows(mesh_tag: str = "16x16"):
+def all_rows(mesh_tag: str = "16x16", *, device_kind: str):
     rows = []
     for arch in ARCH_NAMES:
         cfg = get_config(arch)
         for cell in valid_cells(cfg):
-            rows.append(cell_roofline(arch, cell, mesh_tag))
+            rows.append(cell_roofline(arch, cell, mesh_tag,
+                                      device_kind=device_kind))
     return rows
 
 
@@ -118,11 +125,13 @@ def print_table(rows, md=False):
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--device-kind", required=True,
+                    help="jax device_kind whose peaks price the terms")
     ap.add_argument("--mesh", default="16x16")
     ap.add_argument("--md", action="store_true")
     ap.add_argument("--json", default="")
     args = ap.parse_args()
-    rows = all_rows(args.mesh)
+    rows = all_rows(args.mesh, device_kind=args.device_kind)
     print_table(rows, md=args.md)
     if args.json:
         Path(args.json).write_text(json.dumps(rows, indent=1))

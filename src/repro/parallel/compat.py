@@ -1,16 +1,10 @@
-"""Version compatibility helpers for jax APIs that moved between releases."""
+"""`shard_map` with replication checking off, for the installed jax."""
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):       # jax >= 0.5 top-level API (check_vma)
-    def shard_map_compat(body, *, mesh, in_specs, out_specs):
-        """`shard_map` with replication checking off, on any jax version."""
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                               # experimental home (check_rep)
-    def shard_map_compat(body, *, mesh, in_specs, out_specs):
-        """`shard_map` with replication checking off, on any jax version."""
-        from jax.experimental.shard_map import shard_map
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+
+def shard_map_compat(body, *, mesh, in_specs, out_specs):
+    """`jax.shard_map` with replication checking off."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -294,13 +294,26 @@ class TestTileRules:
         assert rules_of(diags) == {"tile-vmem-overflow"}
 
     def test_vmem_formula_matches_candidate_generator(self):
-        from repro.core import modes
-        assert rules_tile.dense_tile_vmem((8, 128, 128), "fp32") == \
-            4 * (8 * 128 + 128 * 128) + 4 * (8 * 128 + 128)
-        assert rules_tile.dense_tile_vmem((32, 128, 128), "int8") == \
-            1 * (32 * 128 + 128 * 128) + 4 * (32 * 128 + 128)
-        assert rules_tile.dense_tile_vmem((8, 128, 128), "fp32") \
-            < modes.VMEM_BYTES
+        # the rule and the candidate generator share one footprint:
+        # double-buffered blocks, (8|32, 128)-padded, plus accumulators
+        assert tunelib.dense_vmem_bytes((8, 128, 128), "fp32") == \
+            2 * 4 * (8 * 128 + 128 * 128 + 8 * 128) + 3 * 4 * 8 * 128
+        assert tunelib.dense_vmem_bytes((32, 128, 128), "int8") == \
+            (2 * (32 * 128 + 128 * 128 + 4 * 32 * 128 + 2 * 4 * 8 * 128)
+             + 4 * 4 * 32 * 128 + 4 * (32 * 128 + 128 * 128))
+        assert tunelib.dense_vmem_bytes((8, 128, 128), "fp32") \
+            < tunelib.SCOPED_VMEM_BYTES
+        # the compiler's boundary for fp32 (512, bk, 512) tiles lies
+        # between bk=1536 (compiles) and bk=1792 (refused)
+        assert tunelib.dense_vmem_bytes((512, 1536, 512), "fp32") \
+            <= tunelib.SCOPED_VMEM_BYTES
+        assert rules_of(rules_tile.check_dense_tile(
+            (512, 1792, 512), "fp32", "s")) == {"tile-vmem-overflow"}
+        op = OpSpec("dense", (64, 9216), (9216, 4096), spec="mk,kn->mn")
+        for prec in ("fp32", "int8"):
+            for tile in tunelib.candidates_for(op, limit=10 ** 6,
+                                               precision=prec):
+                assert rules_tile.check_dense_tile(tile, prec, "s") == []
 
     def test_tile_precision_mismatch_triggers(self, tmp_cache):
         op = DENSE

@@ -9,7 +9,8 @@ PREAMBLE = """
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.engine.parallel import device_mesh
+mesh = device_mesh((4, 2), ("data", "model"))
 """
 
 
@@ -30,7 +31,7 @@ def test_sharded_train_step_matches_single_device(run_distributed):
 
         losses = {}
         for tag, m in (('sharded', mesh),
-                       ('single', jax.make_mesh((1, 1), ('data', 'model')))):
+                       ('single', device_mesh((1, 1), ('data', 'model')))):
             ts, contract = TS.build_train_step(cfg, m)
             # donation consumes buffers: use fresh copies per mesh
             pc = jax.tree_util.tree_map(lambda a: a.copy(), params)
@@ -111,7 +112,7 @@ def test_elastic_restore_across_meshes(run_distributed, tmp_path):
         tree_sharded = jax.device_put(tree['w'], sh1)
         mgr.save(1, {{'w': tree_sharded}})
         # restore under a DIFFERENT mesh (2x4)
-        mesh2 = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh2 = device_mesh((2, 4), ('data', 'model'))
         sh2 = NamedSharding(mesh2, P('model', 'data'))
         got = mgr.restore(1, {{'w': jnp.zeros((8, 8))}}, {{'w': sh2}})
         ok = bool(jnp.array_equal(got['w'], tree['w']))

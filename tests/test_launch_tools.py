@@ -148,16 +148,17 @@ class TestCollectiveBytes:
 # ---------------------------------------------------------------------------
 
 from repro.configs.base import ARCH_NAMES, get_config, valid_cells  # noqa: E402
-from repro.core.modes import TPU_ICI_BW, TPU_PEAK_FLOPS_BF16  # noqa: E402
+from repro.core.modes import peaks_for  # noqa: E402
 
 ARCH = ARCH_NAMES[0]
 CELL = valid_cells(get_config(ARCH))[0]
+V5E = "TPU v5 lite"
 
 
 class TestCellRoofline:
     def test_analytic_terms_without_dryrun(self, monkeypatch, tmp_path):
         monkeypatch.setattr(roofline, "DRYRUN_DIR", tmp_path)
-        row = cell_row = roofline.cell_roofline(ARCH, CELL)
+        row = cell_row = roofline.cell_roofline(ARCH, CELL, device_kind=V5E)
         assert row["arch"] == ARCH and row["cell"] == CELL
         assert row["compute_s"] > 0 and row["memory_s"] > 0
         assert row["collective_s"] == 0          # no artifact, no bytes
@@ -168,7 +169,7 @@ class TestCellRoofline:
         assert row["peak_gib"] is None
         # compute term is exactly analytic FLOPs over the pod peak
         assert cell_row["compute_s"] == pytest.approx(
-            row["analytic_flops"] / 256 / TPU_PEAK_FLOPS_BF16)
+            row["analytic_flops"] / 256 / peaks_for(V5E).flops_bf16)
 
     def test_merges_dryrun_artifact(self, monkeypatch, tmp_path):
         monkeypatch.setattr(roofline, "DRYRUN_DIR", tmp_path)
@@ -180,8 +181,9 @@ class TestCellRoofline:
         }
         (tmp_path / f"{ARCH}__{CELL}__16x16.json").write_text(
             json.dumps(artifact))
-        row = roofline.cell_roofline(ARCH, CELL)
-        assert row["collective_s"] == pytest.approx(1.5e9 / TPU_ICI_BW)
+        row = roofline.cell_roofline(ARCH, CELL, device_kind=V5E)
+        assert row["collective_s"] == pytest.approx(
+            1.5e9 / peaks_for(V5E).ici_bw)
         assert row["hlo_flops_reported"] == 1.0e15
         assert row["hlo_bytes_reported"] == 2.0e12
         assert row["peak_gib"] == pytest.approx(8.0)
@@ -189,9 +191,14 @@ class TestCellRoofline:
 
     def test_mesh_tag_scales_device_count(self, monkeypatch, tmp_path):
         monkeypatch.setattr(roofline, "DRYRUN_DIR", tmp_path)
-        small = roofline.cell_roofline(ARCH, CELL, "16x16")
-        big = roofline.cell_roofline(ARCH, CELL, "2x16x16")
+        small = roofline.cell_roofline(ARCH, CELL, "16x16", device_kind=V5E)
+        big = roofline.cell_roofline(ARCH, CELL, "2x16x16", device_kind=V5E)
         assert big["compute_s"] == pytest.approx(small["compute_s"] / 2)
+
+    def test_unknown_device_kind_raises(self, monkeypatch, tmp_path):
+        monkeypatch.setattr(roofline, "DRYRUN_DIR", tmp_path)
+        with pytest.raises(KeyError, match="no published peaks"):
+            roofline.cell_roofline(ARCH, CELL, device_kind="cpu")
 
 
 class TestFmtS:
@@ -205,7 +212,7 @@ class TestPrintTable:
     @pytest.fixture
     def rows(self, monkeypatch, tmp_path):
         monkeypatch.setattr(roofline, "DRYRUN_DIR", tmp_path)
-        return [roofline.cell_roofline(ARCH, CELL)]
+        return [roofline.cell_roofline(ARCH, CELL, device_kind=V5E)]
 
     def test_plain(self, rows, capsys):
         roofline.print_table(rows)
@@ -225,7 +232,7 @@ class TestPrintTable:
 class TestAllRows:
     def test_covers_every_valid_cell(self, monkeypatch, tmp_path):
         monkeypatch.setattr(roofline, "DRYRUN_DIR", tmp_path)
-        rows = roofline.all_rows()
+        rows = roofline.all_rows(device_kind=V5E)
         expected = sum(len(valid_cells(get_config(a))) for a in ARCH_NAMES)
         assert len(rows) == expected
         assert {r["arch"] for r in rows} == set(ARCH_NAMES)
