@@ -87,7 +87,10 @@ class _Cursor:
         self.pairs = tuple(pairs)
         self.index = 0
 
-    def next_for(self, op: planlib.OpSpec) -> planlib.EnginePlan:
+    def next_for(self, op: planlib.OpSpec,
+                 ) -> Tuple[planlib.OpSpec, planlib.EnginePlan]:
+        """The compiled (op, plan) pair `op` executes: the compiled op is
+        equal to `op` and carries the program's name for it."""
         if self.index >= len(self.pairs):
             raise RuntimeError(
                 f"compiled program expected {len(self.pairs)} engine ops but "
@@ -102,7 +105,7 @@ class _Cursor:
                 f"{op.kind}{op.x_shape}x{op.w_shape} — recompile for these "
                 "input shapes")
         self.index += 1
-        return plan
+        return want, plan
 
 
 _PROG = _ProgramState()
@@ -147,19 +150,21 @@ def replaying(pairs: Sequence[Tuple[planlib.OpSpec, planlib.EnginePlan]],
             "op sequence")
 
 
-def _plan_for(op: planlib.OpSpec,
-              backend_arg: Optional[str]) -> planlib.EnginePlan:
-    """Capture/replay hook + plan resolution for one issued op."""
+def _plan_for(op: planlib.OpSpec, backend_arg: Optional[str],
+              ) -> Tuple[planlib.OpSpec, planlib.EnginePlan]:
+    """Capture/replay hook + plan resolution for one issued op. Returns
+    the op (under replay the compiled one, which carries the program's
+    name for it) and its plan."""
     for ops, precs in _PROG.capture:
         ops.append(op)
         if precs is not None:
             precs.append(None)      # _pin_precision backfills explicit args
     if _PROG.replay:
-        plan = _PROG.replay[-1].next_for(op)
+        op, plan = _PROG.replay[-1].next_for(op)
         if backend_arg is None:
-            return plan
+            return op, plan
         dispatch.get_backend(backend_arg)          # explicit arg still wins
-        return planlib.plan_op(op, backend_arg)
+        return op, planlib.plan_op(op, backend_arg)
     if backend_arg is not None:
         name = backend_arg
     else:
@@ -167,7 +172,7 @@ def _plan_for(op: planlib.OpSpec,
         name = (planlib.auto_backend(op, cfg.backend)
                 if cfg.policy == "auto" else cfg.backend)
     dispatch.get_backend(name)          # validate before caching a plan
-    return planlib.plan_op(op, name)
+    return op, planlib.plan_op(op, name)
 
 
 def _interp(interpret: Optional[bool]) -> Optional[bool]:
@@ -267,7 +272,7 @@ def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int = 0,
            groups: int = 1, bias: Optional[jax.Array] = None,
            act: Optional[str] = None, backend: Optional[str] = None,
            accum_dtype=_UNSET, precision: Optional[str] = None,
-           interpret: Optional[bool] = None) -> jax.Array:
+           interpret: Optional[bool] = None, name: str = "") -> jax.Array:
     """Conv mode. x: (B,H,W,C_in) NHWC; w: (H_f,W_f,C_in/g,C_out) HWIO.
     Returns (B,H_out,W_out,C_out) in x.dtype.
 
@@ -277,12 +282,16 @@ def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int = 0,
     fused post-ops elsewhere. On the int8 path (`precision="int8"` here or
     on the config) dequant+bias+act fuse into the same writeback, so the
     quantized conv is still one launch; `accum_dtype` is then ignored (the
-    int8 contract pins an exact int32 accumulator)."""
+    int8 contract pins an exact int32 accumulator).
+
+    `name` labels the op (`OpSpec.name`): a compiled program's device ops
+    carry it as their `jax.named_scope` (dispatch.run_op)."""
     op = planlib.OpSpec("conv2d", tuple(map(int, x.shape)),
                         tuple(map(int, w.shape)), stride=int(stride),
-                        pad=int(pad), groups=int(groups))
+                        pad=int(pad), groups=int(groups), name=name)
     _check_epilogue(bias, act, op.w_shape[3], "conv2d")
-    plan = _pin_precision(op, _plan_for(op, backend), precision)
+    op, plan = _plan_for(op, backend)
+    plan = _pin_precision(op, plan, precision)
     plan = _maybe_tile(op, plan)
     ledger_mod.record(plan)
     out = dispatch.run_op(op, plan, lambda be, pl: be.conv2d(
@@ -298,7 +307,7 @@ def conv1d_depthwise(x: jax.Array, w: jax.Array, *, causal: bool = True,
     """1-D depthwise mode (Mamba/xLSTM short conv). x: (B,L,D); w: (W_f,D)."""
     op = planlib.OpSpec("conv1d_dw", tuple(map(int, x.shape)),
                         tuple(map(int, w.shape)), causal=bool(causal))
-    plan = _plan_for(op, backend)
+    op, plan = _plan_for(op, backend)
     ledger_mod.record(plan)
     out = dispatch.run_op(op, plan, lambda be, pl: be.conv1d_depthwise(
         x, w, pl, causal=causal, interpret=_interp(interpret)))
@@ -309,15 +318,16 @@ def einsum(spec: str, x: jax.Array, w: jax.Array, *,
            bias: Optional[jax.Array] = None, act: Optional[str] = None,
            backend: Optional[str] = None, accum_dtype=_UNSET,
            out_dtype=None, precision: Optional[str] = None,
-           interpret: Optional[bool] = None) -> jax.Array:
+           interpret: Optional[bool] = None, name: str = "") -> jax.Array:
     """FC mode for any two-operand dense contraction (weights second).
 
     `bias` ((n_out,), one entry per trailing output feature) and `act`
     ("relu" | "gelu") form the fused epilogue (in-kernel on the Pallas
     GEMM's canonical path, post-ops elsewhere); the trailing output label
-    must be a weight-side (w-free) dim for a bias to be well-defined."""
+    must be a weight-side (w-free) dim for a bias to be well-defined.
+    `name` labels the op, as in `conv2d`."""
     op = planlib.OpSpec("dense", tuple(map(int, x.shape)),
-                        tuple(map(int, w.shape)), spec=spec)
+                        tuple(map(int, w.shape)), spec=spec, name=name)
     structure = planlib.parse_einsum(spec, x.ndim, w.ndim)
     if bias is not None:
         # a per-feature bias needs a weight-side trailing output dim; a
@@ -333,7 +343,8 @@ def einsum(spec: str, x: jax.Array, w: jax.Array, *,
         _check_epilogue(bias, act, n_out, f"einsum {spec!r}")
     elif act is not None:
         _check_epilogue(None, act, 0, f"einsum {spec!r}")
-    plan = _pin_precision(op, _plan_for(op, backend), precision)
+    op, plan = _plan_for(op, backend)
+    plan = _pin_precision(op, plan, precision)
     plan = _maybe_tile(op, plan)
     ledger_mod.record(plan)
     pad = _row_pad_amount(structure, op.x_shape)
@@ -366,7 +377,7 @@ def dense(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
           act: Optional[str] = None, backend: Optional[str] = None,
           accum_dtype=_UNSET, out_dtype=None,
           precision: Optional[str] = None,
-          interpret: Optional[bool] = None) -> jax.Array:
+          interpret: Optional[bool] = None, name: str = "") -> jax.Array:
     """FC mode (W_f = 1): x (..., n) @ w (n, m) -> (..., m), with an
     optional fused bias ((m,)) / activation epilogue."""
     if isinstance(accum_dtype, _Unset):
@@ -374,7 +385,7 @@ def dense(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
     return einsum(planlib.dense_spec(x.ndim), x, w, bias=bias, act=act,
                   backend=backend, accum_dtype=accum_dtype,
                   out_dtype=out_dtype, precision=precision,
-                  interpret=interpret)
+                  interpret=interpret, name=name)
 
 
 def proj(x: jax.Array, w: jax.Array, *, backend: Optional[str] = None,
@@ -409,7 +420,7 @@ def paged_gather(pool: jax.Array, table: jax.Array, *,
     """
     op = planlib.OpSpec("gather", tuple(map(int, pool.shape)),
                         tuple(map(int, table.shape)))
-    plan = _plan_for(op, backend)
+    op, plan = _plan_for(op, backend)
     ledger_mod.record(plan)
     return dispatch.run_op(op, plan, lambda be, pl: dispatch.gather_impl(be)(
         pool, table, pl, interpret=_interp(interpret)))
@@ -421,7 +432,7 @@ def paged_gather(pool: jax.Array, table: jax.Array, *,
 def matmul(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
            act: Optional[str] = None, backend: Optional[str] = None,
            precision: Optional[str] = None,
-           interpret: Optional[bool] = None) -> jax.Array:
+           interpret: Optional[bool] = None, name: str = "") -> jax.Array:
     return dense(x, w, bias=bias, act=act, backend=backend,
                  accum_dtype=jnp.float32, out_dtype=x.dtype,
-                 precision=precision, interpret=interpret)
+                 precision=precision, interpret=interpret, name=name)

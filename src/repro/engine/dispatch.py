@@ -116,14 +116,17 @@ def fallback_chain(name: str) -> Tuple[str, ...]:
 
 
 def run_op(op, plan, call):
-    """Execute one planned op through the kernel-fault chokepoint.
+    """Execute one planned op through the kernel-fault chokepoint, under
+    `jax.named_scope(op.name or op.kind)`: in a compiled program every op
+    has a name (the program's, or its kind and position), and the device
+    ops it lowers to carry that name in their metadata.
 
     `call(backend, plan)` performs the actual backend invocation; every
     engine entrypoint (api.py) routes through here. Three behaviors:
 
       * no injector installed and `EngineConfig.fallback == "none"` (the
-        default): a direct tail call — zero overhead, no exception
-        handling, byte-identical behavior to the pre-fault-layer engine;
+        default): a direct call — no exception handling, the same
+        computation as the pre-fault-layer engine;
       * an installed `serve.faults` injector may fire the "kernel" point
         for this (op kind, backend) visit, raising `KernelFault` exactly
         where a real lowering/execution failure would surface;
@@ -139,6 +142,11 @@ def run_op(op, plan, call):
     time and then replays deterministically — a fallback can never flip
     between steps of a serving loop.
     """
+    with jax.named_scope(op.name or op.kind):
+        return _run_chain(op, plan, call)
+
+
+def _run_chain(op, plan, call):
     from repro.engine.config import current_config
     from repro.serve import faults as _faults
 

@@ -430,7 +430,8 @@ class CompiledNet:
     .plan   — `NetworkPlan` over the program's op graph (Table-4 analytics).
     .cost   — the plan's aggregate Table-4 row (dict).
     .apply  — jitted executor: every engine op runs on its planned backend,
-              in the captured order. Shape-specialized like any compiled
+              in the captured order, under a `jax.named_scope` of its
+              name (dispatch.run_op). Shape-specialized like any compiled
               artifact: executing with shapes that change the op sequence
               raises (recompile instead).
     .mesh   — the (data, model) device mesh `.apply` is `shard_map`ped
@@ -570,6 +571,9 @@ def compile(program: Program,  # noqa: A001 (mirrors engine.compile API)
     exec_pairs = None
     if program.fn is not None:
         exec_ops, exec_precs = _capture_ops(program.fn, program.in_avals)
+        # an op the forward left unnamed is named by kind and position
+        exec_ops = tuple(op if op.name else dataclasses.replace(
+            op, name=f"{op.kind}{i}") for i, op in enumerate(exec_ops))
         # shard decisions are pinned into the exec pairs only when a mesh
         # actually backs them: a sharded plan executes collectives, which
         # only exist inside the shard_mapped body

@@ -257,7 +257,7 @@ def _forward(net: CNNDef, params: Dict, x: jax.Array,
             x = E.conv2d(x, p["w"], stride=cd.stride, pad=cd.pad,
                          groups=cd.groups, bias=p["b"],
                          act="relu" if cd.relu else None,
-                         precision=_prec(precisions, cd.name))
+                         precision=_prec(precisions, cd.name), name=cd.name)
             if cd.pool > 1:
                 x = _maxpool(x, cd.pool)
         x = x.reshape(x.shape[0], -1)
@@ -268,7 +268,7 @@ def _forward(net: CNNDef, params: Dict, x: jax.Array,
         p = params["fc"][fd.name]
         x = E.matmul(x, p["w"], bias=p["b"],
                      act="relu" if fd.relu else None,
-                     precision=_prec(precisions, fd.name))
+                     precision=_prec(precisions, fd.name), name=fd.name)
     return x
 
 
@@ -367,7 +367,7 @@ def _resnet50_body(params: Dict, x: jax.Array,
         # bias (and relu where it directly follows) fused into the engine op
         p = pc[nm]
         return E.conv2d(x, p["w"], stride=stride, pad=pad, bias=p["b"],
-                        act=act, precision=_prec(precisions, nm))
+                        act=act, precision=_prec(precisions, nm), name=nm)
 
     x = conv("conv1", x, 2, 3, act="relu")
     x = _maxpool(jnp.pad(x, ((0, 0), (0, 1), (0, 1), (0, 0)),

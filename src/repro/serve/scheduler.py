@@ -54,6 +54,7 @@ import jax.numpy as jnp
 
 from repro import engine as E
 from repro.engine import ledger as _ledger
+from repro.engine import spans
 from repro.serve import faults as _faults
 from repro.serve.faults import (  # noqa: F401 (re-exported surface)
     FatalError, FaultInjector, TransientError, backoff_s)
@@ -90,7 +91,10 @@ class Ticket:
     batch_fill: int = 0             # real requests in the executed batch
     batch_bucket: int = 0           # padded bucket size the batch ran at
     batch_replica: int = 0          # mesh data group the batch dispatched to
-    done_s: float = 0.0             # completion timestamp (perf_counter)
+    batch_seq: int = -1             # the batch's `serve.step` batch_seq
+    # perf_counter time the batch's results were ready (the end of its
+    # `serve.wait`); with replica spreading, the time it was dispatched
+    done_s: float = 0.0
 
     @property
     def latency_s(self) -> float:
@@ -222,7 +226,8 @@ class Scheduler:
         self._entries: Dict[str, _Entry] = {}
         self._queue: List[Ticket] = []
         self._next_rid = 0
-        self._wall_s = 0.0              # summed dispatch wall time
+        self._batch_seq = 0             # batches formed so far
+        self._wall_s = 0.0              # summed dispatch times (stats())
 
     def _inj_ctx(self):
         """Ambient-injector context for a dispatch: installs this
@@ -355,24 +360,36 @@ class Scheduler:
 
     def _dispatch(self, entry: _Entry, bucket: int,
                   per: Tuple[Tuple[Any, ...], ...],
-                  replica: Optional[int] = None) -> Tuple[Any, ...]:
+                  replica: Optional[int] = None,
+                  ) -> Tuple[Tuple[Any, ...], int, int]:
         """The jitted batch path (pack -> shared-arg splice -> apply ->
         unpack), shared by `step` and `warmup` so the pre-paid traces are
-        exactly the serving traces. With multiple mesh data groups the
-        batch lands on the round-robin replica and the call does NOT block
-        — consecutive batches overlap across replicas; `drain` syncs."""
+        exactly the serving traces. Returns the per-request results and
+        the perf_counter_ns times the dispatch began (`serve.pack`'s
+        start) and the results were ready (`serve.wait`'s end). With
+        multiple mesh data groups the batch lands on the round-robin
+        replica and the call does NOT block — consecutive batches overlap
+        across replicas; `drain` syncs — so the second time is the
+        dispatch's end (`serve.unpack`'s), not readiness."""
         if replica is None:
             replica = self._rr % len(self._groups)
             self._rr += 1
-        packed = iter(self._pack_fn(entry)(per))
-        args = [entry.shared[pos] if pos in entry.shared else next(packed)
-                for pos in range(len(entry.program.in_avals))]
+        with spans.span("serve.pack") as pack:
+            packed = iter(self._pack_fn(entry)(per))
+            args = [entry.shared[pos] if pos in entry.shared
+                    else next(packed)
+                    for pos in range(len(entry.program.in_avals))]
         with self._inj_ctx(), _ledger.tracking(self.fault_ledger):
-            out = self.compiled(entry.name, bucket, replica).apply(*args)
-        results = self._unpack_fn(entry, bucket)(out)
-        if len(self._groups) == 1:
+            net = self.compiled(entry.name, bucket, replica)
+            with spans.span("engine.apply"):
+                out = net.apply(*args)
+        with spans.span("serve.unpack") as unpack:
+            results = self._unpack_fn(entry, bucket)(out)
+        if len(self._groups) > 1:
+            return results, pack.start_ns, unpack.end_ns
+        with spans.span("serve.wait") as wait:
             jax.block_until_ready(results)
-        return results
+        return results, pack.start_ns, wait.end_ns
 
     def warmup(self, name: Optional[str] = None) -> None:
         """Pre-pay every bucket's jit cost before opening traffic: runs one
@@ -389,7 +406,7 @@ class Scheduler:
             for bucket in self.buckets:
                 for replica in range(len(self._groups)):
                     jax.block_until_ready(self._dispatch(
-                        entry, bucket, (zeros,) * bucket, replica=replica))
+                        entry, bucket, (zeros,) * bucket, replica=replica)[0])
 
     # -- admission ----------------------------------------------------------
 
@@ -489,48 +506,67 @@ class Scheduler:
         return self.buckets[-1]
 
     def step(self) -> List[Ticket]:
-        """Form and execute one batch; returns the tickets it served."""
-        self._expire()
-        if not self._queue:
-            return []
-        if self.faults is not None:
-            spike = self.faults.latency("step")
-            if spike:
-                self._spikes += 1
-                time.sleep(spike)
-        name = self._pick_model()
-        entry = self._entries[name]
-        batch = [t for t in self._queue if t.model == name][:self.max_batch]
-        self._queue = [t for t in self._queue if t not in batch]
-        k = len(batch)
-        bucket = self._bucket_for(k)
+        """Form and execute one batch; returns the tickets it served.
 
-        t0 = time.perf_counter()
-        # pad at the ticket level: repeat the first request's arg pytrees
-        # (array references, no copies) so the jitted packer always sees
-        # exactly `bucket` request tuples
-        per = tuple(t.args for t in batch) + (batch[0].args,) * (bucket - k)
-        replica = self._rr % len(self._groups)
-        self._rr += 1
-        results = self._dispatch(entry, bucket, per, replica=replica)
-        wall = time.perf_counter() - t0
-        self._wall_s += wall
-        entry.batches += 1
-        entry.served += k
-        entry.padded_slots += bucket - k
+        The call is one `serve.step` span (engine/spans.py) with the
+        attributes `queue_depth` (pending at entry) and, once a batch is
+        formed, `model`, `bucket`, `rows` and `batch_seq`, which every
+        ticket of the batch keeps. Its children tile it in order:
+        `serve.form` (expire, pick, select, pad), `serve.pack`,
+        `engine.apply`, `serve.unpack`, `serve.wait` (blocking on the
+        results) and `serve.account` (per-ticket fields and ledgers)."""
+        with spans.span("serve.step", queue_depth=len(self._queue)) as sp:
+            return self._step(sp.attrs)
 
-        for i, ticket in enumerate(batch):
-            ticket.result = results[i]
-            ticket.args = ()    # served: release the request inputs
-            ticket.done = True
-            ticket.batch_index = i
-            ticket.batch_fill = k
-            ticket.batch_bucket = bucket
-            ticket.batch_replica = replica
-            ticket.done_s = time.perf_counter()
-            for plan in entry.unit_plan.plans:
-                ticket.ledger.record_plan(plan)
-                self.ledger.record_plan(plan)
+    def _step(self, attrs: Dict[str, Any]) -> List[Ticket]:
+        with spans.span("serve.form"):
+            self._expire()
+            if not self._queue:
+                return []
+            if self.faults is not None:
+                spike = self.faults.latency("step")
+                if spike:
+                    self._spikes += 1
+                    time.sleep(spike)
+            name = self._pick_model()
+            entry = self._entries[name]
+            batch = [t for t in self._queue
+                     if t.model == name][:self.max_batch]
+            self._queue = [t for t in self._queue if t not in batch]
+            k = len(batch)
+            bucket = self._bucket_for(k)
+            # pad at the ticket level: repeat the first request's arg
+            # pytrees (array references, no copies) so the jitted packer
+            # always sees exactly `bucket` request tuples
+            per = (tuple(t.args for t in batch)
+                   + (batch[0].args,) * (bucket - k))
+            replica = self._rr % len(self._groups)
+            self._rr += 1
+        self._batch_seq += 1
+        seq = self._batch_seq
+        attrs.update(model=name, bucket=bucket, rows=k, batch_seq=seq)
+        results, t0, t1 = self._dispatch(entry, bucket, per,
+                                         replica=replica)
+        self._wall_s += (t1 - t0) * 1e-9
+        done_s = t1 * 1e-9
+
+        with spans.span("serve.account"):
+            entry.batches += 1
+            entry.served += k
+            entry.padded_slots += bucket - k
+            for i, ticket in enumerate(batch):
+                ticket.result = results[i]
+                ticket.args = ()    # served: release the request inputs
+                ticket.done = True
+                ticket.batch_index = i
+                ticket.batch_fill = k
+                ticket.batch_bucket = bucket
+                ticket.batch_replica = replica
+                ticket.batch_seq = seq
+                ticket.done_s = done_s
+                for plan in entry.unit_plan.plans:
+                    ticket.ledger.record_plan(plan)
+                    self.ledger.record_plan(plan)
         return batch
 
     def drain(self) -> List[Ticket]:
@@ -547,6 +583,11 @@ class Scheduler:
     # -- stats --------------------------------------------------------------
 
     def stats(self) -> Dict[str, Any]:
+        """Serving counters. `dispatch_wall_s` sums each batch's dispatch,
+        from its `serve.pack` start to its results' readiness (the end of
+        `serve.wait`; over replicas, of `serve.unpack`), on the spans'
+        clock; `throughput_rps` is `served` over it. Forming the batch,
+        an injected latency spike and the accounting are outside it."""
         per_model = {
             n: {
                 "served": e.served,
