@@ -13,6 +13,11 @@ Two artefacts live here:
    loaded once and reused W_f x C_out times — the paper's "input pixels are
    read once per clock cycle while weights loop on-chip", re-expressed for a
    memory hierarchy (HBM -> VMEM -> MXU) instead of shift registers.
+   Where a conv has fewer than `FOLD_MAX_C_IN` input channels per group
+   (the RGB stems), `conv2d_gfid` folds the stride phases and the filter
+   taps into the contraction instead (`folds_taps`): a K = C_in product
+   fills only C_in of a vector register's 128 lanes, so H_f * W_f of them
+   would move and multiply ~40x the bytes the conv needs.
 
 These are the pure-JAX reference semantics; `repro.kernels.gfid_conv` is the
 Pallas TPU kernel with explicit BlockSpec VMEM tiling implementing the same
@@ -59,6 +64,50 @@ def active_neurons_per_cycle(w_f: int, stride: int, n_out: int) -> int:
 # Shifted-GEMM convolution (the TPU-native GFID lowering)
 # ---------------------------------------------------------------------------
 
+# Below this many input channels per group a per-tap GEMM fills too few
+# lanes: fold the stride phases and taps into the contraction instead.
+FOLD_MAX_C_IN = 16
+
+
+def folds_taps(w_shape) -> bool:
+    """True where `conv2d_gfid` lowers a conv with filters of shape
+    `w_shape` (HWIO, C_in per group) by folding its stride phases and
+    width taps into the contraction, False where it runs the band loop."""
+    return w_shape[2] < FOLD_MAX_C_IN
+
+
+def _conv_folded(x: jax.Array, w: jax.Array, stride: int, h_out: int,
+                 w_out: int, accum_dtype: jnp.dtype) -> jax.Array:
+    """Valid conv of padded x (B, H, W, C) with w (H_f, W_f, C, O), the
+    stride phases and width taps folded into the contraction.
+
+    Space to depth by S makes the conv stride-1 over ceil(H_f/S) x
+    ceil(W_f/S) taps of S*S*C channels each; the filter is zero-padded to
+    whole taps (exact zeros change no sum). The width taps are then
+    concatenated on channels, leaving one einsum with
+    K = ceil(W_f/S)*S*S*C per height tap."""
+    s = stride
+    h_f, w_f, c, o = w.shape
+    th, tw = -(-h_f // s), -(-w_f // s)
+    hq, wq = h_out - 1 + th, w_out - 1 + tw
+    b = x.shape[0]
+    # Rows and columns past H and W meet only the zero taps.
+    x = x[:, :hq * s, :wq * s]
+    x = jnp.pad(x, ((0, 0), (0, hq * s - x.shape[1]),
+                    (0, wq * s - x.shape[2]), (0, 0)))
+    x = x.reshape(b, hq, s, wq, s, c).transpose(0, 1, 3, 2, 4, 5)
+    x = x.reshape(b, hq, wq, s * s * c)
+    x = jnp.concatenate([x[:, :, q:q + w_out] for q in range(tw)], axis=-1)
+    w = jnp.pad(w, ((0, th * s - h_f), (0, tw * s - w_f), (0, 0), (0, 0)))
+    w = w.reshape(th, s, tw, s, c, o).transpose(0, 2, 1, 3, 4, 5)
+    w = w.reshape(th, tw * s * s * c, o)
+    acc = jnp.zeros((b, h_out, w_out, o), dtype=accum_dtype)
+    for p in range(th):
+        acc = acc + jnp.einsum("bhwc,cd->bhwd", x[:, p:p + h_out], w[p],
+                               preferred_element_type=accum_dtype)
+    return acc
+
+
 def conv2d_gfid(x: jax.Array, w: jax.Array, stride: int = 1, pad: int = 0,
                 groups: int = 1,
                 accum_dtype: jnp.dtype = jnp.float32) -> jax.Array:
@@ -79,6 +128,12 @@ def conv2d_gfid(x: jax.Array, w: jax.Array, stride: int = 1, pad: int = 0,
     slice + GEMM over C_in. This is exactly the GFID banded-matrix product
     evaluated band-by-band: band (j, i) of M contributes
     X[:, zS+j, tS+i, :] @ W[j, i] to every output pixel (z, t).
+
+    With fewer than `FOLD_MAX_C_IN` channels per group (`folds_taps`) that
+    GEMM would fill C_in of 128 lanes, so each group instead runs
+    `_conv_folded`: the same products, the stride phases and width taps
+    folded into K, one einsum per ceil(H_f/S) height tap. Only the order
+    of the sums changes.
     """
     if x.ndim != 4 or w.ndim != 4:
         raise ValueError(f"expected NHWC x and HWIO w, got {x.shape} {w.shape}")
@@ -94,8 +149,14 @@ def conv2d_gfid(x: jax.Array, w: jax.Array, stride: int = 1, pad: int = 0,
     out_shards = []
     cg = c_in // groups
     og = c_out // groups
+    fold = folds_taps(w.shape)
     for g in range(groups):
         xg = x[..., g * cg:(g + 1) * cg]
+        if fold:
+            out_shards.append(_conv_folded(
+                xg, w[..., g * og:(g + 1) * og], stride, h_out, w_out,
+                accum_dtype))
+            continue
         acc = jnp.zeros((b, h_out, w_out, og), dtype=accum_dtype)
         for j in range(h_f):
             for i in range(w_f):

@@ -32,7 +32,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
-from repro.core import modes
+from repro.core import gfid, modes
 from repro.engine import api
 from repro.engine import parallel as parlib
 from repro.engine import tune as tunelib
@@ -507,6 +507,18 @@ class CompiledNet:
         the int8 contract does not cover, whatever the config asked for."""
         pairs = self.exec_pairs if self.exec_pairs is not None else ()
         return tuple(plan.precision for _, plan in pairs)
+
+    def lowerings(self) -> Tuple[str, ...]:
+        """Per-op lowering, in call order: for an fp32 conv on the "xla"
+        backend "fold" where `gfid.conv2d_gfid` folds its taps into the
+        contraction (`gfid.folds_taps`) and "band" where it runs the band
+        loop; for every other op its backend."""
+        pairs = self.exec_pairs if self.exec_pairs is not None else ()
+        return tuple(
+            ("fold" if plan.precision != "int8"
+             and gfid.folds_taps(op.w_shape) else "band")
+            if op.kind == "conv2d" and plan.backend == "xla"
+            else plan.backend for op, plan in pairs)
 
 
 def compile(program: Program,  # noqa: A001 (mirrors engine.compile API)

@@ -86,6 +86,41 @@ class TestShiftedGemmConv:
         y2 = gfid.conv2d_reference(x, w, stride=s, pad=p, groups=g)
         np.testing.assert_allclose(y1, y2, rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("x_shape,w_shape,s,p,g", [
+        ((2, 224, 224, 3), (7, 7, 3, 64), 2, 3, 1),    # ResNet-50 stem
+        ((1, 227, 227, 3), (11, 11, 3, 96), 4, 0, 1),  # AlexNet conv1
+        ((2, 32, 32, 3), (3, 3, 3, 64), 1, 1, 1),      # VGG-16 conv1_1
+        ((2, 13, 11, 1), (3, 3, 1, 8), 2, 1, 1),       # C_in 1
+        ((2, 17, 19, 6), (5, 5, 3, 8), 2, 2, 2),       # grouped, 3 a group
+        ((1, 23, 22, 3), (7, 7, 3, 8), 3, 0, 1),       # H, W not multiples of S
+    ], ids=["resnet50_stem", "alexnet_conv1", "vgg16_conv1_1", "c_in_1",
+            "grouped", "ragged"])
+    def test_folded_taps_match_xla_conv(self, x_shape, w_shape, s, p, g):
+        """Small-C_in convs take the folded lowering and match XLA's conv."""
+        assert gfid.folds_taps(w_shape)
+        x = jax.random.normal(jax.random.PRNGKey(0), x_shape, jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(1), w_shape, jnp.float32)
+        y1 = gfid.conv2d_gfid(x, w, stride=s, pad=p, groups=g)
+        y2 = gfid.conv2d_reference(x, w, stride=s, pad=p, groups=g)
+        np.testing.assert_allclose(y1, y2, rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("c_in", [16, 64, 256])
+    def test_band_loop_kept_from_16_channels(self, c_in):
+        """From 16 channels a group the lowering is the band loop, bitwise."""
+        assert not gfid.folds_taps((3, 3, c_in, 8))
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 9, c_in))
+        w = jax.random.normal(jax.random.PRNGKey(1), (3, 3, c_in, 8))
+        xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+        want = jnp.zeros((2, 5, 5, 8), jnp.float32)
+        for j in range(3):
+            for i in range(3):
+                xs = jax.lax.slice(xp, (0, j, i, 0), (2, j + 9, i + 9, c_in),
+                                   (1, 2, 2, 1))
+                want = want + jnp.einsum("bhwc,cd->bhwd", xs, w[j, i],
+                                         preferred_element_type=jnp.float32)
+        got = gfid.conv2d_gfid(x, w, stride=2, pad=1)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
     @given(l=st.integers(4, 32), d=st.sampled_from([4, 8]),
            w_f=st.sampled_from([2, 4, 7]), causal=st.booleans())
     @settings(max_examples=25, deadline=None)

@@ -276,6 +276,21 @@ class TestAutoPolicy:
                                    np.asarray(fixed.apply(w, x)),
                                    rtol=2e-4, atol=2e-4)
 
+    def test_lowerings_fold_only_the_stem(self):
+        # ResNet-50's conv1 (C_in 3) is the one conv whose taps fold; every
+        # other conv under "auto" is Pallas or keeps the XLA band loop.
+        compiled = E.compile(cnn.program("resnet50"),
+                             E.EngineConfig(policy="auto"))
+        lowerings = compiled.lowerings()
+        assert len(lowerings) == len(compiled.backends()) == 54
+        folded = [op.name for (op, _), low in zip(compiled.exec_pairs,
+                                                  lowerings) if low == "fold"]
+        assert folded == ["conv1"]
+        assert set(lowerings) == {"fold", "band", "pallas"}
+        pallas = E.compile(cnn.program("alexnet"),
+                           E.EngineConfig(backend="pallas"))
+        assert set(pallas.lowerings()) == {"pallas"}
+
     def test_eager_auto_policy(self):
         x = jnp.ones((64, 256))
         w = jnp.ones((256, 128))

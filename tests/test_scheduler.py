@@ -71,6 +71,7 @@ class TestGoldenParity:
         done = sched.drain()
         assert [t.batch_bucket for t in done] == [2, 2, 1]
         alone = E.compile(prog, serving_config)
+        assert alone.lowerings()[0] == "fold"      # conv1: C_in 3, taps folded
         for t, x in zip(tickets, xs):
             want = alone.apply(params, x)
             np.testing.assert_array_equal(np.asarray(t.result),

@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import engine as E
+from repro.core import gfid
 from repro.engine import tune
 from repro.kernels import ops
 from repro.models import cnn
@@ -143,3 +144,15 @@ def test_alexnet_program_compiles_at_highest_precision(one_chip):
     assert "precision=None" not in jaxpr
     text = lowered.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 8
+
+
+def test_folded_stem_is_one_highest_dot_a_height_tap(one_chip):
+    """ResNet-50's stem on the XLA backend (C_in 3, taps folded) compiles
+    to ceil(7/2) = 4 dots at "highest" and slices no (B, 112, 112, 3) tap
+    view out of the input."""
+    def fn(x, w):
+        return gfid.conv2d_gfid(x, w, stride=2, pad=3)
+    with jax.default_matmul_precision("highest"):
+        text = _compiled_text(fn, one_chip, (8, 224, 224, 3), (7, 7, 3, 64))
+    assert text.count("operand_precision={highest,highest}") == 4
+    assert "f32[8,112,112,3]" not in text
