@@ -200,3 +200,18 @@ def test_unnamed_ops_are_named_by_kind_and_position():
     net = E.compile(prog)
     assert [op.name for op, _ in net.exec_pairs] == ["conv2d0", "dense1"]
     assert {"conv2d0", "dense1"} <= _scopes(net, prog)
+
+
+@pytest.mark.parametrize("net,some", [
+    ("vgg16", {"conv1_1", "conv3_2", "fc6"}),
+    ("resnet50", {"conv1", "s3b2_3x3", "fc"}),
+])
+def test_lowered_benchmark_nets_carry_every_plan_op_name(net, some):
+    """Under the benchmark configurations' `policy="auto"` too, every plan
+    op's device ops carry its name scope, by which a trace ties device
+    time to the op."""
+    prog = cnn.program(net).with_batch(2)
+    cnet = E.compile(prog, E.EngineConfig(policy="auto"))
+    names = {op.name for op, _ in cnet.exec_pairs}
+    assert some <= names
+    assert names <= _scopes(cnet, prog)
