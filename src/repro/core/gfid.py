@@ -17,7 +17,11 @@ Two artefacts live here:
    (the RGB stems), `conv2d_gfid` folds the stride phases and the filter
    taps into the contraction instead (`folds_taps`): a K = C_in product
    fills only C_in of a vector register's 128 lanes, so H_f * W_f of them
-   would move and multiply ~40x the bytes the conv needs.
+   would move and multiply ~40x the bytes the conv needs. `fold_operands` is
+   that relayout, for both lowerings: this module contracts it with one
+   einsum per height tap, and the Pallas wrapper
+   (`repro.kernels.ops.gfid_conv2d`, fp32) passes it to the conv kernel
+   at stride 1.
 
 These are the pure-JAX reference semantics; `repro.kernels.gfid_conv` is the
 Pallas TPU kernel with explicit BlockSpec VMEM tiling implementing the same
@@ -27,7 +31,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,22 +74,27 @@ FOLD_MAX_C_IN = 16
 
 
 def folds_taps(w_shape) -> bool:
-    """True where `conv2d_gfid` lowers a conv with filters of shape
-    `w_shape` (HWIO, C_in per group) by folding its stride phases and
-    width taps into the contraction, False where it runs the band loop."""
+    """True where `conv2d_gfid` and the fp32 Pallas wrapper lower a conv
+    with filters of shape `w_shape` (HWIO, C_in per group) over
+    `fold_operands`, its stride phases and width taps in the contraction;
+    False where they run the band loop and the strided kernel."""
     return w_shape[2] < FOLD_MAX_C_IN
 
 
-def _conv_folded(x: jax.Array, w: jax.Array, stride: int, h_out: int,
-                 w_out: int, accum_dtype: jnp.dtype) -> jax.Array:
-    """Valid conv of padded x (B, H, W, C) with w (H_f, W_f, C, O), the
-    stride phases and width taps folded into the contraction.
+def fold_operands(x: jax.Array, w: jax.Array, stride: int, h_out: int,
+                  w_out: int) -> Tuple[jax.Array, jax.Array]:
+    """The operands of a valid conv of padded x (B, H, W, C) with w
+    (H_f, W_f, C, O), the stride phases and width taps folded into the
+    contraction: x_f (B, H_q, W_out, K) and w_f (T_h, K, O), with
+    K = ceil(W_f/S)*S*S*C and T_h = ceil(H_f/S). Output row z is
+    sum over p < T_h of x_f[:, z + p] @ w_f[p]: a stride-1 conv of x_f
+    with a (T_h, 1) filter.
 
     Space to depth by S makes the conv stride-1 over ceil(H_f/S) x
     ceil(W_f/S) taps of S*S*C channels each; the filter is zero-padded to
     whole taps (exact zeros change no sum). The width taps are then
-    concatenated on channels, leaving one einsum with
-    K = ceil(W_f/S)*S*S*C per height tap."""
+    concatenated on channels. `_conv_folded` (XLA) and the Pallas conv
+    wrapper (`kernels.ops.gfid_conv2d`) both contract this relayout."""
     s = stride
     h_f, w_f, c, o = w.shape
     th, tw = -(-h_f // s), -(-w_f // s)
@@ -100,9 +109,17 @@ def _conv_folded(x: jax.Array, w: jax.Array, stride: int, h_out: int,
     x = jnp.concatenate([x[:, :, q:q + w_out] for q in range(tw)], axis=-1)
     w = jnp.pad(w, ((0, th * s - h_f), (0, tw * s - w_f), (0, 0), (0, 0)))
     w = w.reshape(th, s, tw, s, c, o).transpose(0, 2, 1, 3, 4, 5)
-    w = w.reshape(th, tw * s * s * c, o)
-    acc = jnp.zeros((b, h_out, w_out, o), dtype=accum_dtype)
-    for p in range(th):
+    return x, w.reshape(th, tw * s * s * c, o)
+
+
+def _conv_folded(x: jax.Array, w: jax.Array, stride: int, h_out: int,
+                 w_out: int, accum_dtype: jnp.dtype) -> jax.Array:
+    """Valid conv of padded x (B, H, W, C) with w (H_f, W_f, C, O) over
+    `fold_operands`' relayout: one einsum per height tap."""
+    x, w = fold_operands(x, w, stride, h_out, w_out)
+    acc = jnp.zeros((x.shape[0], h_out, w_out, w.shape[-1]),
+                    dtype=accum_dtype)
+    for p in range(w.shape[0]):
         acc = acc + jnp.einsum("bhwc,cd->bhwd", x[:, p:p + h_out], w[p],
                                preferred_element_type=accum_dtype)
     return acc

@@ -509,16 +509,22 @@ class CompiledNet:
         return tuple(plan.precision for _, plan in pairs)
 
     def lowerings(self) -> Tuple[str, ...]:
-        """Per-op lowering, in call order: for an fp32 conv on the "xla"
-        backend "fold" where `gfid.conv2d_gfid` folds its taps into the
-        contraction (`gfid.folds_taps`) and "band" where it runs the band
-        loop; for every other op its backend."""
+        """Per-op lowering, in call order: for an fp32 conv on the "xla" or
+        "pallas" backend "fold" where its stride phases and taps fold into
+        the contraction (`gfid.folds_taps`: `gfid.conv2d_gfid` on XLA, the
+        Pallas wrapper `kernels.ops.gfid_conv2d` over `gfid.fold_operands`),
+        "band" where the XLA lowering runs the band loop; for every other
+        op its backend."""
+        def lowering(op, plan) -> str:
+            if op.kind == "conv2d" and plan.backend in ("xla", "pallas"):
+                if plan.precision != "int8" and gfid.folds_taps(op.w_shape):
+                    return "fold"
+                if plan.backend == "xla":
+                    return "band"
+            return plan.backend
+
         pairs = self.exec_pairs if self.exec_pairs is not None else ()
-        return tuple(
-            ("fold" if plan.precision != "int8"
-             and gfid.folds_taps(op.w_shape) else "band")
-            if op.kind == "conv2d" and plan.backend == "xla"
-            else plan.backend for op, plan in pairs)
+        return tuple(lowering(op, plan) for op, plan in pairs)
 
 
 def compile(program: Program,  # noqa: A001 (mirrors engine.compile API)

@@ -16,7 +16,12 @@ Stride S > 1: the wrapper splits each input row into its S stride phases,
 (B, H, W, C) -> (B, H, S, ceil(W/S), C), so filter tap i reads phase i % S
 at offset i // S as one contiguous slice. Mosaic refuses a strided slice
 of a loaded vector; the phase split keeps the tap order, and so the
-accumulation order, of the plain strided loop.
+accumulation order, of the plain strided loop. A conv with fewer than
+`gfid.FOLD_MAX_C_IN` input channels per group (AlexNet's conv1) never
+reaches this kernel strided: `ops.gfid_conv2d` passes it the relayout
+`gfid.fold_operands`, a stride-1 conv with one width tap over
+K = ceil(W_f/S)*S*S*C, since a C-lane minor dim would be padded to 128
+lanes here.
 
 Channel tiling `(c_in_block, c_out_block)` is an explicit knob (the
 per-layer resource adaptation of `engine.tune`): ragged channel counts fall

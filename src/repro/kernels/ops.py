@@ -15,7 +15,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import quant
+from repro.core import gfid, quant
 from repro.kernels import conv1d as _conv1d
 from repro.kernels import flash_attention as _flash
 from repro.kernels import gfid_conv as _conv
@@ -41,6 +41,12 @@ def gfid_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int = 0,
     kernel default; `engine.tune` passes per-layer winners). `bias` (C_out,)
     and `act` ("relu" | "gelu") run as a fused in-kernel epilogue.
 
+    In fp32, a conv with fewer than `gfid.FOLD_MAX_C_IN` input channels per
+    group (`gfid.folds_taps`, AlexNet's conv1) runs the kernel at stride 1
+    over `gfid.fold_operands`, the relayout `gfid.conv2d_gfid` folds on
+    XLA: stride phases and width taps in the contraction, its whole K one
+    C_in block. Only the order of the sums changes.
+
     `precision="int8"` quantizes both operands symmetrically (per-example
     activation scales, per-channel weight scales), runs the int8 kernel
     with an exact int32 VMEM accumulator, and fuses dequant+bias+act into
@@ -60,11 +66,10 @@ def gfid_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int = 0,
     cib, cob = tile if tile is not None else _conv.DEFAULT_CONV_TILE
     if pad:
         x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+    conv = partial(_conv_fp32, stride=stride, cib=cib, cob=cob, act=act,
+                   interpret=interpret)
     if groups == 1:
-        out = _conv.gfid_conv2d_nhwc(x, w, stride=stride, c_in_block=cib,
-                                     c_out_block=cob, bias=bias, act=act,
-                                     interpret=interpret)
-        return out.astype(x.dtype)
+        return conv(x, w, bias).astype(x.dtype)
     b, h_in, w_in, c_in = x.shape
     h_f, w_f, cg, c_out = w.shape
     og = c_out // groups
@@ -72,20 +77,33 @@ def gfid_conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int = 0,
     xg = jnp.moveaxis(x.reshape(b, h_in, w_in, groups, cg), 3, 0)
     wg = jnp.moveaxis(w.reshape(h_f, w_f, cg, groups, og), 3, 0)
     if bias is None and act is None:
-        outs = jax.vmap(
-            lambda xv, wv: _conv.gfid_conv2d_nhwc(
-                xv, wv, stride=stride, c_in_block=cib, c_out_block=cob,
-                interpret=interpret))(xg, wg)
+        outs = jax.vmap(lambda xv, wv: conv(xv, wv, None))(xg, wg)
     else:
         bg = (jnp.zeros((c_out,), jnp.float32) if bias is None
               else bias.astype(jnp.float32)).reshape(groups, og)
-        outs = jax.vmap(
-            lambda xv, wv, bv: _conv.gfid_conv2d_nhwc(
-                xv, wv, stride=stride, c_in_block=cib, c_out_block=cob,
-                bias=bv, act=act, interpret=interpret))(xg, wg, bg)
+        outs = jax.vmap(conv)(xg, wg, bg)
     # (G,B,Ho,Wo,og) -> (B,Ho,Wo,G*og) with groups major in C_out.
     return jnp.moveaxis(outs, 0, 3).reshape(
         b, outs.shape[2], outs.shape[3], c_out).astype(x.dtype)
+
+
+def _conv_fp32(x: jax.Array, w: jax.Array, bias: Optional[jax.Array], *,
+               stride: int, cib: int, cob: int, act: Optional[str],
+               interpret: bool) -> jax.Array:
+    """One group's valid fp32 conv on the GFID kernel. Where
+    `gfid.folds_taps`, the kernel runs at stride 1 over the relayout
+    `gfid.fold_operands`, one height tap a grid step and its whole K one
+    C_in block: for AlexNet's conv1 one (55, 144) x (144, 96) dot a step,
+    not eleven with K = 3 on a stride-phase copy padded from 3 lanes to
+    128."""
+    if gfid.folds_taps(w.shape):
+        h_out = (x.shape[1] - w.shape[0]) // stride + 1
+        w_out = (x.shape[2] - w.shape[1]) // stride + 1
+        x, w_f = gfid.fold_operands(x, w, stride, h_out, w_out)
+        w, stride, cib = w_f[:, None], 1, w_f.shape[1]
+    return _conv.gfid_conv2d_nhwc(x, w, stride=stride, c_in_block=cib,
+                                  c_out_block=cob, bias=bias, act=act,
+                                  interpret=interpret)
 
 
 def _gfid_conv2d_int8(x: jax.Array, w: jax.Array, *, stride: int, pad: int,

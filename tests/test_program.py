@@ -287,9 +287,12 @@ class TestAutoPolicy:
                                                   lowerings) if low == "fold"]
         assert folded == ["conv1"]
         assert set(lowerings) == {"fold", "band", "pallas"}
+        # Under backend="pallas" AlexNet's conv1 (C_in 3) folds in the
+        # Pallas wrapper; every op still runs on Pallas.
         pallas = E.compile(cnn.program("alexnet"),
                            E.EngineConfig(backend="pallas"))
-        assert set(pallas.lowerings()) == {"pallas"}
+        assert pallas.lowerings() == ("fold",) + ("pallas",) * 7
+        assert set(pallas.backends()) == {"pallas"}
 
     def test_eager_auto_policy(self):
         x = jnp.ones((64, 256))
