@@ -3,11 +3,10 @@ through the multi-mode engine and print the MMIE-projected per-layer
 analytics (Fig. 5) alongside the functional forward pass.
 
 Uses the plan-based `repro.engine` API: the forward pass is wrapped in
-`engine.tracking()`, which yields the analytic `Ledger` (identical totals
-to the legacy `MultiModeEngine` ledger). `--compiled` switches to the
-two-phase path instead: `engine.compile(cnn.program(net), EngineConfig)`
-plans the whole network up front (Table-4 aggregates with no forward pass)
-and runs the jitted `CompiledNet.apply`.
+`engine.tracking()`, which yields the analytic `Ledger`. `--compiled`
+switches to the two-phase path instead: `engine.compile(cnn.program(net),
+EngineConfig)` plans the whole network up front (Table-4 aggregates with
+no forward pass) and runs the jitted `CompiledNet.apply`.
 
   PYTHONPATH=src python examples/cnn_inference.py [--net resnet50]
   PYTHONPATH=src python examples/cnn_inference.py --compiled --policy auto

@@ -8,8 +8,7 @@ Two layers over one diagnostics model (stable rule ids, severities, JSON):
     every registered program by `python -m repro.analyze`;
   * layer 2 (`rules_ast`): custom `ast` rules over the `src/repro/`
     source tree enforcing structural invariants (engine routing, no
-    mutable globals, guarded fault hooks, deterministic kernel bodies,
-    contained deprecated surface).
+    mutable globals, guarded fault hooks, deterministic kernel bodies).
 
 See README "Static analysis" for the rule catalog and allowlisting.
 """

@@ -1,6 +1,6 @@
 """AST repo-invariant rules (layer 2): `ast`-based lint over `src/repro/`.
 
-Five custom rules encode the repo's structural invariants — the things the
+Four custom rules encode the repo's structural invariants — the things the
 test suites can't see because they are about *how the source is written*,
 not what it computes:
 
@@ -26,15 +26,10 @@ not what it computes:
     `*_kernel`) must be bitwise-reproducible: no wall clocks, no stdlib /
     numpy RNG, no `id()`/`hash()` (`jax.random` with an explicit key is
     deterministic and allowed).
-  * deprecated-surface — the PR-3 deprecation shims (`MultiModeEngine`,
-    `default_engine`, `set_default_backend`, `set_interpret`) may only be
-    referenced from the modules that define/re-export them; new call sites
-    inside src/repro must use the functional engine API.
 
 Suppression: a finding is dropped when its source line carries
 `# analyze: allow[<rule-id>]`. Module-wide allowlists live in
-`RAW_DENSE_MODULE_ALLOW` / `DEPRECATED_MODULE_ALLOW` with the reason
-recorded next to each entry.
+`RAW_DENSE_MODULE_ALLOW` with the reason recorded next to each entry.
 """
 from __future__ import annotations
 
@@ -64,11 +59,6 @@ register_rule(Rule(
     id="kernel-nondeterminism", severity="error", layer="ast",
     contract="Pallas kernel bodies must be bitwise-reproducible: no wall "
              "clocks, no stdlib/numpy RNG, no id()/hash()"))
-register_rule(Rule(
-    id="deprecated-surface", severity="error", layer="ast",
-    contract="the deprecated core.MultiModeEngine surface may only be "
-             "referenced by its own shim/re-export modules; new code uses "
-             "the functional engine API"))
 
 _PRAGMA = re.compile(r"#\s*analyze:\s*allow\[([a-z0-9-]+(?:,\s*[a-z0-9-]+)*)\]")
 
@@ -91,16 +81,6 @@ RAW_DENSE_MODULE_ALLOW: Dict[str, str] = {
 # raw dense math is the *job* of these subtrees
 RAW_DENSE_TREE_ALLOW: Tuple[str, ...] = ("kernels", "core", "engine",
                                          "launch", "analyze", "configs")
-
-DEPRECATED_MODULE_ALLOW: Dict[str, str] = {
-    "core/engine.py": "defines the deprecation shim",
-    "core/__init__.py": "re-exports the shim for legacy imports",
-    "engine/config.py": "defines set_default_backend/set_interpret",
-    "engine/api.py": "re-exports the config helpers",
-    "engine/__init__.py": "re-exports the config helpers",
-}
-DEPRECATED_NAMES: Tuple[str, ...] = ("MultiModeEngine", "default_engine",
-                                     "set_default_backend", "set_interpret")
 
 _DENSE_NP_ROOTS = {"jnp", "np", "numpy"}
 _DENSE_NP_ATTRS = {"einsum", "dot", "matmul", "tensordot", "vdot", "inner"}
@@ -361,37 +341,11 @@ class _FileLinter:
                             "derive randomness from an explicit key "
                             "outside the kernel if needed")
 
-    # -- deprecated-surface -------------------------------------------------
-
-    def check_deprecated(self) -> None:
-        if self.rel in DEPRECATED_MODULE_ALLOW:
-            return
-        fix = ("use the functional engine API (engine.compile / "
-               "using_backend / EngineConfig) — the legacy surface only "
-               "lives on for out-of-tree callers")
-        for node in ast.walk(self.tree):
-            hit: Optional[str] = None
-            if isinstance(node, ast.ImportFrom):
-                for alias in node.names:
-                    if alias.name in DEPRECATED_NAMES:
-                        hit = alias.name
-                        break
-            elif isinstance(node, ast.Name) and node.id in DEPRECATED_NAMES:
-                hit = node.id
-            elif isinstance(node, ast.Attribute) \
-                    and node.attr in DEPRECATED_NAMES:
-                hit = node.attr
-            if hit is not None:
-                self.emit("deprecated-surface", node,
-                          f"reference to deprecated {hit!r} outside its "
-                          "shim modules", fix)
-
     def run(self) -> List[Diagnostic]:
         self.check_raw_dense()
         self.check_mutable_global()
         self.check_fault_hooks()
         self.check_kernel_determinism()
-        self.check_deprecated()
         self.out.sort(key=lambda d: d.site)
         return self.out
 

@@ -79,8 +79,9 @@ def check_dense_tile(tile: Sequence[int], precision: str,
     if bad:
         out.append(finding(
             "tile-misaligned", site, "; ".join(bad),
-            fix="re-tune the op (python -m benchmarks.run --retune) or "
-                "drop the entry so the kernel default applies"))
+            fix="drop the entry so the kernel default applies, or re-tune "
+                "it: engine.compile under EngineConfig(tuning=\"autotune\") "
+                "benchmarks a missing op and persists the winner"))
     vmem = tunelib.dense_vmem_bytes((bm, bk, bn), precision)
     if vmem > tunelib.SCOPED_VMEM_BYTES:
         out.append(finding(
@@ -181,7 +182,8 @@ def doctor_cache(path: Path, known_keys: Optional[Set[str]] = None,
             f"cache version {raw.get('version')!r} != current "
             f"{tunelib.CACHE_VERSION} (stale caches load as empty)",
             severity="warn",
-            fix="regenerate with `python -m benchmarks.run --retune`"))
+            fix="regenerate with engine.compile under "
+                "EngineConfig(tuning=\"autotune\")"))
     bad_keys: List[str] = []
     for key, entry in sorted(raw["entries"].items()):
         esite = f"{site}#{key}"

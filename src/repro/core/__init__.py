@@ -1,7 +1,5 @@
 # The paper's primary contribution: the GFID dataflow (gfid.py), its analytic
 # performance model (analytics.py, Eqs 8-18) and the mode table (modes.py).
-# The multi-mode engine itself now lives in `repro.engine` (plan-based,
-# functional); `MultiModeEngine` / `default_engine` below are a deprecation
-# shim kept importable for one release.
-from repro.core.engine import EngineConfig, MultiModeEngine, default_engine  # noqa: F401
+# The multi-mode engine itself lives in `repro.engine` (plan-based,
+# functional).
 from repro.core.modes import Mode, fc_mode, paper_mode, pes_per_tile  # noqa: F401

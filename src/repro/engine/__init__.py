@@ -11,7 +11,7 @@ Two-phase compile/execute model (the paper's network-level scheduling):
 
   * `EngineConfig` (config.py)  — frozen, hashable execution config
     (backend, interpret, accum, policy); ambient via `using_config`,
-    jit-static friendly. `using_backend` / `set_interpret` are thin shims.
+    jit-static friendly. `using_backend` is a thin shim over it.
   * `Program` / `NetworkPlan` / `compile` (program.py) — ordered op graphs
     from layer tables (`models.cnn.program`) or traced forwards
     (`trace_program`), planned whole-network into Table-4 aggregates and a
@@ -24,16 +24,12 @@ Per-op pieces (all pure, jit-friendly, singleton-free):
     alone: Table-3 mode, MXU tiling, analytic cost (Eqs. 15-18);
   * backend registry (dispatch.py) — "pallas" / "xla" / "ref", extensible
     via `register_backend`;
-  * `Ledger` + `tracking()` (ledger.py) — explicit analytics, replacing the
-    old process-global `default_engine()` singleton;
+  * `Ledger` + `tracking()` (ledger.py) — explicit, scoped analytics;
   * kernel autotuner (tune.py) — per-op Pallas tile configs, benchmarked
     once and persisted to `.tuning/<device_kind>.json`, selected by
     `EngineConfig.tuning` and pinned at `engine.compile` time. Every
     dense/conv op also takes `bias=` / `act=` — a fused epilogue applied
     in the kernel's fp32 accumulator on the Pallas backend.
-
-Legacy `repro.core.MultiModeEngine` remains as a deprecation shim over this
-package for one release.
 """
 from repro.engine import tune  # noqa: F401
 from repro.engine.api import (  # noqa: F401
@@ -41,8 +37,7 @@ from repro.engine.api import (  # noqa: F401
     proj, replaying)
 from repro.engine.config import (  # noqa: F401
     EngineConfig, current_config, default_backend, in_config_context,
-    set_default_backend, set_default_config, set_interpret, using_backend,
-    using_config)
+    set_default_config, using_backend, using_config)
 from repro.engine.dispatch import (  # noqa: F401
     EPILOGUE_ACTS, EngineBackend, apply_epilogue, backend_names, get_backend,
     register_backend)

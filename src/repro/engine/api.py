@@ -26,7 +26,7 @@ Numerics: `accum_dtype=None` (the default for `einsum`) reproduces a plain
 model onto the engine is bit-identical. `dense` defaults to fp32
 accumulation (`preferred_element_type=jnp.float32`), the convention of
 every parameter GEMM in `repro.models`. `out_dtype` casts the result when
-given (the legacy engine always cast back to `x.dtype`).
+given (`matmul` always casts back to `x.dtype`).
 """
 from __future__ import annotations
 
@@ -41,8 +41,8 @@ import jax.numpy as jnp
 from repro.engine import dispatch, ledger as ledger_mod, plan as planlib
 from repro.engine import tune as tunelib
 from repro.engine.config import (  # noqa: F401 (re-exported compat surface)
-    EngineConfig, current_config, default_backend, set_default_backend,
-    set_default_config, set_interpret, using_backend, using_config)
+    EngineConfig, current_config, default_backend, set_default_config,
+    using_backend, using_config)
 
 
 class _Unset:
@@ -426,8 +426,7 @@ def paged_gather(pool: jax.Array, table: jax.Array, *,
         pool, table, pl, interpret=_interp(interpret)))
 
 
-# `matmul` mirrors the legacy `MultiModeEngine.matmul` contract exactly:
-# fp32 accumulation, result cast back to the input dtype (the fused
+# `matmul`: fp32 accumulation, result cast back to the input dtype (the fused
 # epilogue, when given, runs before the cast — i.e. in fp32).
 def matmul(x: jax.Array, w: jax.Array, *, bias: Optional[jax.Array] = None,
            act: Optional[str] = None, backend: Optional[str] = None,

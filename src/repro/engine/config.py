@@ -14,18 +14,15 @@ bools it is hashable and equality-comparable, so it can be
 Ambient resolution keeps working via a *thread-local* stack of configs:
 `using_config(cfg)` (and the thin `using_backend(name)` shim over it)
 pushes for the dynamic extent of a block; `current_config()` reads the top.
-The process-wide base config is set with `set_default_config` /
-`set_default_backend` / `set_interpret` — which now raise `RuntimeError`
-when called inside an active context instead of being silently shadowed
-until the context pops (the old stack wrote index 0 while contexts
-pushed/popped the same list, so the write was invisible).
+The process-wide base config is set with `set_default_config`, which
+raises `RuntimeError` when called inside an active context instead of being
+silently shadowed until the context pops.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import threading
-import warnings
 from typing import Iterator, List, Optional
 
 from repro.engine.parallel import ParallelConfig
@@ -225,24 +222,3 @@ def set_default_config(cfg: EngineConfig) -> None:
     dispatch.get_backend(cfg.backend)
     _require_no_context("set_default_config()")
     _BASE[0] = cfg
-
-
-def set_default_backend(name: str) -> None:
-    warnings.warn(
-        "set_default_backend() is deprecated; use using_backend(name) for "
-        "scoped selection or set_default_config() for the process base",
-        DeprecationWarning, stacklevel=2)
-    from repro.engine import dispatch
-    dispatch.get_backend(name)              # validate eagerly
-    _require_no_context("set_default_backend()")
-    _BASE[0] = _BASE[0].replace(backend=name)
-
-
-def set_interpret(interpret: bool) -> None:
-    """Whether Pallas kernels run in interpret mode (True on CPU)."""
-    warnings.warn(
-        "set_interpret() is deprecated; use using_config(current_config()"
-        ".replace(interpret=...)) or set_default_config()",
-        DeprecationWarning, stacklevel=2)
-    _require_no_context("set_interpret()")
-    _BASE[0] = _BASE[0].replace(interpret=bool(interpret))

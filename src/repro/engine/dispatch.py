@@ -1,8 +1,8 @@
 """Pluggable backend registry for the multi-mode engine.
 
-Replaces the if/elif backend chains of the old `core.engine.MultiModeEngine`
-with named, registrable backends. A backend implements the three op kinds of
-the engine against a precomputed `EnginePlan`:
+Named, registrable backends, in place of if/elif chains over a backend
+string. A backend implements the three op kinds of the engine against a
+precomputed `EnginePlan`:
 
   * ``"xla"``    — pure-JAX GFID lowering (`core.gfid` shifted GEMMs); the
                    default everywhere.

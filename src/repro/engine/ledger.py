@@ -1,8 +1,8 @@
 """Explicit analytic ledgers for the engine (paper Eqs. 15-18, Fig. 5).
 
-The old `MultiModeEngine` kept a mutable ledger on a process-global engine
-object — hostile to multi-model serving and confusing under jit. Here the
-ledger is an explicit object activated by a context manager:
+The ledger is an explicit object activated by a context manager, not state
+kept on a process-global engine object, so several models can be served and
+priced side by side, and jit does not confuse it:
 
     with engine.tracking() as ledger:
         logits = apply_model(params, x)
@@ -35,8 +35,7 @@ from repro.engine.plan import EnginePlan
 
 @dataclasses.dataclass
 class OpRecord:
-    """One executed engine op. Field names match the legacy
-    `core.engine.OpRecord` so existing ledger consumers keep working."""
+    """One executed engine op."""
 
     kind: str                       # "conv2d" | "conv1d_dw" | "matmul" | "dense"
     mode: modes.Mode

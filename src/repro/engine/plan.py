@@ -8,7 +8,7 @@ pad / groups / backend): no array data, no mutable state. That makes it
   * usable as a dict key / static jit argument (frozen dataclass of ints,
     strings and `modes.Mode`),
 
-which is exactly what the old stateful `MultiModeEngine` was not. Each plan
+and nothing about it depends on where or how often it is called. Each plan
 carries the paper-side schedule (the Table-3 mode and its analytic cost,
 Eqs. 15-18) and the TPU-side schedule (the MXU tile triple of
 `modes.mxu_tiling_for_mode`) for the op, so dispatch, analytics and any
@@ -17,9 +17,8 @@ future tiling policy all read from one object.
 Einsum planning: a dense contraction `einsum(spec, x, w)` is classified per
 axis label into batch (x, w and out), contraction (x and w, not out),
 x-free and w-free dims. Its FC-mode cost is `fc_cost(n=prod(contract),
-m=prod(w_free))` scaled by every remaining x dim — identical to how the old
-engine booked `matmul` for the 2-D case, generalized to stacked-expert and
-transposed-head weights.
+m=prod(w_free))` scaled by every remaining x dim — the 2-D `matmul` cost,
+generalized to stacked-expert and transposed-head weights.
 """
 from __future__ import annotations
 
@@ -428,10 +427,13 @@ def plan_einsum(spec: str, x_shape: Shape, w_shape: Shape,
                     f"{dims[lab]} vs {size}")
     # math.prod of an empty tuple is 1 (no contract labels = outer product,
     # one MAC per output element); a genuine zero-size dim propagates a
-    # zero-work plan (0 MACs, 0 cycles) instead of being rounded up.
+    # zero-work plan (0 MACs, 0 cycles, 0 memory words) instead of being
+    # rounded up.
     n = math.prod(dims[l] for l in st.contract)
     m = math.prod(dims[l] for l in st.w_free)
     reps = math.prod(dims[l] for l in st.batch + st.x_free)
+    if n == 0 or m == 0:
+        reps = 0
     fc = analytics.fc_cost(analytics.FCLayerSpec("fc", n, m))
     mode = modes.fc_mode()
     return EnginePlan(

@@ -54,7 +54,7 @@ Tile = Tuple[int, ...]
 CACHE_VERSION = 2
 CACHE_DIR_ENV = "REPRO_TUNING_DIR"
 MAX_CANDIDATES = 10         # benchmarked per op after analytic pruning
-BENCH_REPEATS = 3           # min-of-N wallclock per candidate
+TIMING_REPEATS = 3          # min-of-N wallclock per candidate
 
 # Analytic pruning weights: one grid step is priced like LAUNCH_MACS
 # MAC-equivalents (kernel launch / revisit overhead), so the score
@@ -84,9 +84,9 @@ def repo_root() -> Optional[Path]:
 def _default_dir() -> Path:
     """`.tuning/` anchored at the repo root when one is detectable, else
     CWD-relative. Anchoring means `tuning="cached"` finds the committed
-    cache — and `--retune` refreshes it — no matter which directory the
-    process was launched from; the CWD fallback covers installed-package
-    layouts."""
+    cache — and `tuning="autotune"` extends it — no matter which directory
+    the process was launched from; the CWD fallback covers
+    installed-package layouts."""
     root = repo_root()
     return root / ".tuning" if root is not None else Path(".tuning")
 
@@ -378,7 +378,7 @@ def _bench_once(fn, args, repeats: int) -> float:
 
 
 def benchmark_tile(op: planlib.OpSpec, tile: Tile, cfg: EngineConfig,
-                   repeats: int = BENCH_REPEATS,
+                   repeats: int = TIMING_REPEATS,
                    precision: str = "fp32") -> float:
     """Min-of-N wallclock of the real Pallas kernel for `op` at `tile`,
     on the precision's actual path (quantize + int8 kernel when int8)."""
@@ -434,7 +434,7 @@ def lookup(op: planlib.OpSpec, cfg: EngineConfig,
 
 
 def autotune_op(op: planlib.OpSpec, cfg: EngineConfig,
-                repeats: int = BENCH_REPEATS,
+                repeats: int = TIMING_REPEATS,
                 precision: str = "fp32") -> Optional[Tile]:
     """Benchmark the pruned candidate grid for `op`, persist and return the
     winner (None when the op has no tile knob). Cached winners are reused —
@@ -488,8 +488,9 @@ def attach(op: planlib.OpSpec, plan: planlib.EnginePlan, cfg: EngineConfig,
 
 def tune_program(ops: Sequence[planlib.OpSpec], cfg: EngineConfig) -> int:
     """Autotune every tunable Pallas op in `ops`; returns the number of
-    ops that now have a cache entry (convenience for warm-up scripts and
-    `benchmarks.run --retune`)."""
+    ops that now have a cache entry (convenience for warm-up scripts;
+    `engine.compile` under `EngineConfig(tuning="autotune")` does the same
+    for the ops it compiles)."""
     tuned = 0
     for op in ops:
         backend = (planlib.auto_backend(op, cfg.backend)

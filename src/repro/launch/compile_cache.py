@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, kept where the next run finds it.
 
-`enable()` is called from the entry points' `main()` (chip_smoke.py,
-benchmarks/run.py, the examples), never while a module is imported. With
+`enable()` is called from the entry points' `main()` (chip_smoke.py, the
+examples), never while a module is imported. With
 JAX_COMPILATION_CACHE_DIR set, JAX takes the directory from the environment
 and this module sets no other. Without it the cache lives at the fixed
 `<repo root>/.jax_cache`, which git ignores. The directory is part of what

@@ -15,7 +15,6 @@ counting; the functional path uses the real geometry.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import math
@@ -272,8 +271,8 @@ def _forward(net: CNNDef, params: Dict, x: jax.Array,
     return x
 
 
-def apply_cnn(name: str, params: Dict, x: jax.Array,
-              engine=None, *, backend: Optional[str] = None,
+def apply_cnn(name: str, params: Dict, x: jax.Array, *,
+              backend: Optional[str] = None,
               config: Optional[E.EngineConfig] = None,
               precisions: Optional[Dict[str, str]] = None) -> jax.Array:
     """Eager forward pass through the multi-mode engine. x: (B, H, W, 3) ->
@@ -283,25 +282,17 @@ def apply_cnn(name: str, params: Dict, x: jax.Array,
     shim selecting just the engine backend ("pallas" | "xla" | "ref");
     `precisions` maps layer names to per-layer precision overrides (e.g.
     ``{"fc6": "int8"}`` — wins over the config's `precision`); wrap
-    the call in `E.tracking()` to collect the MMIE analytics ledger. The
-    `engine` argument still accepts a legacy `core.MultiModeEngine` (its
-    backend and ledger are honored) but is deprecated. For the jitted,
-    whole-network-planned path use `engine.compile(program(name), cfg)`.
+    the call in `E.tracking()` to collect the MMIE analytics ledger. For the
+    jitted, whole-network-planned path use `engine.compile(program(name),
+    cfg)`.
     """
-    if engine is not None:          # legacy shim path
-        backend = engine.config.backend
-        track = (E.tracking(engine.ledger) if engine.config.track_analytics
-                 else contextlib.nullcontext())
-    else:
-        track = contextlib.nullcontext()
     if config is not None and backend is not None:
-        raise ValueError("pass config or backend (or a legacy engine), "
-                         "not both")
+        raise ValueError("pass config or backend, not both")
     net = CNNS[name]
     _check_precisions(net, precisions)
     ctx = E.using_config(config) if config is not None \
         else E.using_backend(backend)
-    with track, ctx:
+    with ctx:
         return _forward(net, params, x, precisions)
 
 

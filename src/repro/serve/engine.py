@@ -44,28 +44,21 @@ def decode_state_shapes(cfg: ModelConfig, batch: int, max_len: int):
         lambda: T.init_decode_state(cfg, batch, max_len))
 
 
-def _engine_ctx(engine_config: Optional[E.EngineConfig],
-                engine_backend: Optional[str]):
-    """Ambient-engine context factory for a traced step. `engine_config`
-    threads a full frozen `engine.EngineConfig`; `engine_backend` is the
-    deprecated string shim (backend only) kept for existing call sites."""
-    if engine_config is not None and engine_backend is not None:
-        raise ValueError("pass engine_config or engine_backend, not both "
-                         "(engine_backend is the deprecated string shim)")
+def _engine_ctx(engine_config: Optional[E.EngineConfig]):
+    """Ambient-engine context for a traced step: `engine_config` for its
+    extent, or the ambient config when None."""
     if engine_config is not None:
         return E.using_config(engine_config)
-    return E.using_backend(engine_backend)
+    return contextlib.nullcontext()
 
 
 def build_serve_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
                      rules: Optional[S.ShardingRules] = None,
-                     engine_config: Optional[E.EngineConfig] = None,
-                     engine_backend: Optional[str] = None):
+                     engine_config: Optional[E.EngineConfig] = None):
     """Returns (jitted step, contract). step(params, state, tokens, pos) ->
     (logits, state'); state donated. `engine_config` selects the
     multi-mode-engine configuration (backend, interpret, accum, policy) for
-    every dense op traced into the step; `engine_backend` remains as the
-    deprecated backend-string shim."""
+    every dense op traced into the step."""
     rules = rules or S.make_rules(mesh)
     defs = T.model_defs(cfg)
     param_specs = S.tree_specs(defs, rules, mesh)
@@ -76,7 +69,7 @@ def build_serve_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
                        tp_axis=rules.tp_axis, remat=False, shard_fn=shard_fn)
 
     def step(params, state, tokens, pos):
-        with _engine_ctx(engine_config, engine_backend):
+        with _engine_ctx(engine_config):
             logits, state2 = T.decode_step(cfg, params, state, tokens, pos,
                                            ctx)
         tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
@@ -96,10 +89,9 @@ def build_serve_step(cfg: ModelConfig, mesh: Mesh, batch: int, max_len: int,
 
 def build_prefill(cfg: ModelConfig, mesh: Mesh, batch: int, seq: int,
                   max_len: int, rules: Optional[S.ShardingRules] = None,
-                  engine_config: Optional[E.EngineConfig] = None,
-                  engine_backend: Optional[str] = None):
+                  engine_config: Optional[E.EngineConfig] = None):
     """Prefill (or encoder forward): returns (jitted fn, contract).
-    `engine_config` / deprecated `engine_backend` as in `build_serve_step`."""
+    `engine_config` as in `build_serve_step`."""
     rules = rules or S.make_rules(mesh)
     defs = T.model_defs(cfg)
     param_specs = S.tree_specs(defs, rules, mesh)
@@ -109,12 +101,12 @@ def build_prefill(cfg: ModelConfig, mesh: Mesh, batch: int, seq: int,
 
     if cfg.is_encoder:
         def fn(params, batch_in):
-            with _engine_ctx(engine_config, engine_backend):
+            with _engine_ctx(engine_config):
                 hidden, _ = T.forward(cfg, params, batch_in, ctx)
                 return T.logits_fn(cfg, params, hidden)
     else:
         def fn(params, batch_in):
-            with _engine_ctx(engine_config, engine_backend):
+            with _engine_ctx(engine_config):
                 return T.prefill(cfg, params, batch_in, max_len, ctx)
 
     def batch_spec(x):

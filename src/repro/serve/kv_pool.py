@@ -102,7 +102,8 @@ class BlockAllocator:
         return sum(sum(1 for b in t if b) for t in self.tables.values())
 
     def register(self, rid: int) -> None:
-        """Open an (empty) block table for request `rid`."""
+        """Open an (empty) block table for request `rid`. Raises
+        `ValueError` if `rid` is already registered (live)."""
         if rid in self.tables:
             raise ValueError(f"request {rid} already registered")
         self.tables[rid] = [0] * self.blocks_per_req

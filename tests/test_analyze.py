@@ -42,7 +42,7 @@ ALL_RULE_IDS = {
     "shard-indivisible", "shard-exact-breach", "shard-inexact-optin",
     # ast
     "raw-dense-bypass", "mutable-global", "fault-hook-unguarded",
-    "kernel-nondeterminism", "deprecated-surface",
+    "kernel-nondeterminism",
 }
 
 DENSE = OpSpec(kind="dense", x_shape=(4, 256), w_shape=(256, 128),
@@ -519,23 +519,6 @@ class TestAstRules:
                 return time.time()       # not a kernel body: fine
             """) == []
 
-    def test_deprecated_surface_triggers(self, tmp_path):
-        diags = _lint_snippet(tmp_path, "serve/old.py", """\
-            from repro.core.engine import MultiModeEngine
-
-            def make():
-                return MultiModeEngine()
-            """)
-        assert rules_of(diags) == {"deprecated-surface"}
-
-    def test_deprecated_surface_allowlist_names_the_shims(self):
-        assert set(rules_ast.DEPRECATED_MODULE_ALLOW) == {
-            "core/engine.py", "core/__init__.py", "engine/config.py",
-            "engine/api.py", "engine/__init__.py"}
-        assert set(rules_ast.DEPRECATED_NAMES) == {
-            "MultiModeEngine", "default_engine", "set_default_backend",
-            "set_interpret"}
-
 
 # ---------------------------------------------------------------------------
 # clean sweeps (the CI gates)
@@ -599,33 +582,6 @@ class TestCompileVerify:
         with pytest.raises(ValueError, match="verify"):
             engine.compile(cnn.program("alexnet"), EngineConfig(),
                            verify="loud")
-
-
-# ---------------------------------------------------------------------------
-# deprecation sweep (satellite): legacy surface still warns
-# ---------------------------------------------------------------------------
-
-class TestDeprecatedSurfaceStillWarns:
-    def test_multimode_engine_warns(self):
-        from repro import core
-        with pytest.warns(DeprecationWarning,
-                          match="MultiModeEngine is deprecated"):
-            core.MultiModeEngine()
-
-    def test_default_engine_warns(self):
-        from repro.core import engine as core_engine
-        core_engine._DEFAULT = None          # force shim re-construction
-        with pytest.warns(DeprecationWarning):
-            core_engine.default_engine()
-
-    def test_set_default_backend_warns(self):
-        with pytest.warns(DeprecationWarning,
-                          match="set_default_backend"):
-            engine.set_default_backend("xla")
-
-    def test_set_interpret_warns(self):
-        with pytest.warns(DeprecationWarning, match="set_interpret"):
-            engine.set_interpret(True)
 
 
 # ---------------------------------------------------------------------------

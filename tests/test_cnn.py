@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import EngineConfig, MultiModeEngine
+from repro import engine as E
 from repro.core.quant import (ACT_FORMAT, WEIGHT_FORMAT, quantization_snr_db,
                               quantize)
 from repro.models import cnn
@@ -27,9 +27,7 @@ def test_backends_agree_alexnet():
     x = jax.random.normal(key, (1, 227, 227, 3), jnp.float32) * 0.1
     outs = {}
     for backend in ("xla", "ref"):
-        eng = MultiModeEngine(EngineConfig(backend=backend,
-                                           track_analytics=False))
-        outs[backend] = cnn.apply_cnn("alexnet", params, x, eng)
+        outs[backend] = cnn.apply_cnn("alexnet", params, x, backend=backend)
     np.testing.assert_allclose(outs["xla"], outs["ref"], rtol=2e-3,
                                atol=2e-3)
     assert outs["xla"].shape == (1, 1000)
@@ -39,17 +37,17 @@ def test_engine_ledger_matches_table4_shape():
     key = jax.random.PRNGKey(0)
     params = cnn.init_cnn("alexnet", key)
     x = jax.random.normal(key, (1, 227, 227, 3), jnp.float32)
-    eng = MultiModeEngine(EngineConfig(backend="xla", track_analytics=True))
-    cnn.apply_cnn("alexnet", params, x, eng)
-    conv_records = [r for r in eng.ledger if r.kind == "conv2d"]
-    fc_records = [r for r in eng.ledger if r.kind == "matmul"]
+    with E.tracking() as led:
+        cnn.apply_cnn("alexnet", params, x, backend="xla")
+    conv_records = [r for r in led if r.kind == "conv2d"]
+    fc_records = [r for r in led if r.kind == "matmul"]
     assert len(conv_records) == 5 and len(fc_records) == 3
     # ledger MACs equal the analytic census
     cm, fm = cnn.total_macs("alexnet")
     assert sum(r.macs for r in conv_records) == cm
     assert sum(r.macs for r in fc_records) == fm
     # total efficiency in the paper's ballpark
-    assert 0.5 < eng.performance_efficiency < 1.0
+    assert 0.5 < led.performance_efficiency < 1.0
 
 
 def test_fixed_point_quantization():

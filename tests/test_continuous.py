@@ -215,6 +215,25 @@ class TestStatsAndPlan:
         assert 1 in st["compiled_decode_buckets"] or \
             st["compiled_decode_buckets"]
 
+    def test_continuous_fills_more_decode_rows_than_drain(
+            self, smollm_reduced, smollm_params):
+        """Short and long requests, queue deeper than the batch: drain
+        admission leaves the rows of finished short requests idle until the
+        batch empties, continuous admission refills them the same step."""
+        work = [([1 + (i * 7 + j) % 199 for j in range(4 if i % 2 else 8)],
+                 2 if i % 2 else 14) for i in range(8)]
+        stats = {}
+        for admission in ("drain", "continuous"):
+            s = make_sched(smollm_reduced, smollm_params, buckets=(4,),
+                           admission=admission)
+            tickets = [s.submit(p, n) for p, n in work]
+            s.run()
+            assert all(t.status == "done" for t in tickets)
+            stats[admission] = s.stats()
+        assert (stats["continuous"]["decode_fill"]
+                > stats["drain"]["decode_fill"])
+        assert stats["continuous"]["steps"] < stats["drain"]["steps"]
+
     def test_paged_decode_plan_prices_gather(self, smollm_reduced,
                                              serving_config):
         """The paged decode program's NetworkPlan carries the gather

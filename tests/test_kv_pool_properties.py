@@ -30,12 +30,14 @@ BLOCK_SIZE = 4
 
 
 def _drive(alloc, trace):
-    """Apply a raw op trace, swallowing the documented errors."""
+    """Apply a raw op trace to live ids only (re-registering a live id is
+    a documented `ValueError`), swallowing `PoolExhausted`."""
     cap = alloc.blocks_per_req * BLOCK_SIZE - 1
     for kind, rid, pos in trace:
         try:
             if kind == "register":
-                alloc.register(rid)
+                if rid not in alloc.tables:
+                    alloc.register(rid)
             elif kind == "ensure":
                 if rid in alloc.tables:
                     alloc.ensure(rid, min(pos, cap), BLOCK_SIZE)

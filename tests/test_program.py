@@ -318,10 +318,11 @@ class TestConfigThreading:
         assert y.shape == (1, 1000)
         assert all(r.plan.backend == "ref" for r in led)
 
-    def test_serve_rejects_both_config_and_backend(self):
-        from repro.serve.engine import _engine_ctx
+    def test_apply_cnn_rejects_both_config_and_backend(self):
+        x = jnp.zeros((1, 227, 227, 3), jnp.float32)
         with pytest.raises(ValueError, match="not both"):
-            _engine_ctx(E.EngineConfig(), "xla")
+            cnn.apply_cnn("alexnet", {}, x, backend="xla",
+                          config=E.EngineConfig())
 
     def test_serve_step_accepts_engine_config(self, smollm_reduced,
                                               host_mesh, smollm_params):
